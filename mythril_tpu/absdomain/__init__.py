@@ -34,6 +34,7 @@ from typing import List, Optional, Sequence
 
 from mythril_tpu.native.bitblast import Unsupported
 from mythril_tpu.smt.terms import Term
+from mythril_tpu.support.device import DeviceFailure
 
 __all__ = ["prefilter_batch", "refute", "reset_state"]
 
@@ -97,14 +98,15 @@ def _evaluate_rows(rows: List[Sequence[Term]]) -> List[Optional[bool]]:
 
 
 def _eval_kb(pack):
-    """Known-bits pass: device interpreter when warm, host numpy otherwise."""
+    """Known-bits pass: device interpreter when warm, host numpy otherwise.
+    A device failure raises ``DeviceFailure``; it never degrades to host."""
     from mythril_tpu.absdomain import device, domains
 
     if device.should_use_device():
         try:
             return device.run_kb(pack)
-        except Exception:
-            pass  # any device hiccup degrades to host, never to a verdict
+        except Exception as e:
+            raise DeviceFailure(f"absdomain known-bits kernel: {e!r}") from e
     return domains.eval_kb_host(pack)
 
 
@@ -113,8 +115,9 @@ def prefilter_batch(
 ) -> List[bool]:
     """One abstract verdict per constraint row; True = proven UNSAT.
 
-    Never raises: unsupported structure, oversized tapes, or internal
-    errors all degrade to False (fall through to the exact tiers).
+    Unsupported structure, oversized tapes, or internal host errors all
+    degrade to False (fall through to the exact tiers); only a device
+    failure (``DeviceFailure``) raises.
     """
     n = len(conjunct_sets)
     results: List[Optional[bool]] = [None] * n
@@ -137,6 +140,8 @@ def prefilter_batch(
         t0 = time.perf_counter()
         try:
             verdicts = _evaluate_rows([list(conjunct_sets[i]) for i in fresh_idx])
+        except DeviceFailure:
+            raise
         except Exception:
             verdicts = [None] * len(fresh_idx)
         h_eval.observe(time.perf_counter() - t0)

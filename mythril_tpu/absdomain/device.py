@@ -16,8 +16,6 @@ the first compile, and callers use the host known-bits pass until
 
 from __future__ import annotations
 
-import logging
-import threading
 from typing import Tuple
 
 import numpy as np
@@ -25,15 +23,11 @@ import numpy as np
 from mythril_tpu.absdomain import domains
 from mythril_tpu.absdomain.tape import LIMBS, U32, PackedBatch
 from mythril_tpu.native.bitblast import OP_VAR
-
-log = logging.getLogger(__name__)
+from mythril_tpu.support.device import DeviceWarmup, note_host_route, on_tpu
 
 # (node, row) padding buckets; row chunks above the cap are split by run_kb
 NODE_BUCKETS = (512, 4096)
 ROW_BUCKETS = (16, 64)
-
-_warm_lock = threading.Lock()
-_warm_state = "cold"  # cold -> warming -> ready
 
 
 def _jax():
@@ -115,7 +109,9 @@ def _dense_overrides(pack: PackedBatch, rows) -> Tuple[np.ndarray, np.ndarray]:
     return okm, okv
 
 
-def _run_chunk(pack: PackedBatch, rows) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+def chunk_args(pack: PackedBatch, rows) -> tuple:
+    """The jitted interpreter's inputs for ``rows`` of ``pack``, padded to
+    their (node, row) bucket."""
     n, r = pack.n_nodes, len(rows)
     nb = _bucket(n, NODE_BUCKETS)
     rb = _bucket(r, ROW_BUCKETS)
@@ -142,10 +138,13 @@ def _run_chunk(pack: PackedBatch, rows) -> Tuple[np.ndarray, np.ndarray, np.ndar
     wa = np.where(a0 >= 0, w[np.maximum(a0, 0)], 0).astype(np.int32)
     wb = np.where(a1 >= 0, w[np.maximum(a1, 0)], 0).astype(np.int32)
 
-    km, kv, refuted = _get_jitted()(
-        op, w, pad_nodes(pack.x0), pad_nodes(pack.x1), a0, a1, a2,
-        wa, wb, wm, pad_nodes(pack.c_limbs), okm_p, okv_p,
-    )
+    return (op, w, pad_nodes(pack.x0), pad_nodes(pack.x1), a0, a1, a2,
+            wa, wb, wm, pad_nodes(pack.c_limbs), okm_p, okv_p)
+
+
+def _run_chunk(pack: PackedBatch, rows) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    n, r = pack.n_nodes, len(rows)
+    km, kv, refuted = _get_jitted()(*chunk_args(pack, rows))
     return (np.asarray(km)[:n, :r], np.asarray(kv)[:n, :r],
             np.asarray(refuted)[:r])
 
@@ -171,70 +170,28 @@ def run_kb(pack: PackedBatch) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
 # ---------------------------------------------------------------------------
 
 
-def _backend() -> str:
-    try:
-        import jax
+def _compile_smallest_bucket() -> None:
+    from mythril_tpu.absdomain import tape as _t
+    from mythril_tpu.smt import terms
 
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
-
-
-def _compile_claimed() -> None:
-    global _warm_state
-    try:
-        from mythril_tpu.absdomain import tape as _t
-        from mythril_tpu.smt import terms
-
-        x = terms.var("_prefilter_warm", 256)
-        pack = _t.pack([[terms.eq(x, terms.const(1, 256))]])
-        _run_chunk(pack, [0])
-        with _warm_lock:
-            _warm_state = "ready"
-    except BaseException:
-        with _warm_lock:
-            _warm_state = "cold"  # allow a later retry
-        raise
+    x = terms.var("_prefilter_warm", 256)
+    pack = _t.pack([[terms.eq(x, terms.const(1, 256))]])
+    _run_chunk(pack, [0])
 
 
-def warmup() -> None:
-    """Compile the smallest bucket synchronously (idempotent)."""
-    global _warm_state
-    with _warm_lock:
-        if _warm_state != "cold":
-            return
-        _warm_state = "warming"
-    _compile_claimed()
-
-
-def ensure_warming() -> None:
-    """Kick the compile on a background thread (claimed under the lock,
-    so back-to-back callers never spawn duplicate compile threads)."""
-    global _warm_state
-    with _warm_lock:
-        if _warm_state != "cold":
-            return
-        _warm_state = "warming"
-
-    def _guarded():
-        try:
-            _compile_claimed()
-        except Exception:
-            log.debug("prefilter device warmup failed; host path stays", exc_info=True)
-
-    threading.Thread(target=_guarded, daemon=False,
-                     name="prefilter-warmup").start()
-
-
-def interpreter_ready() -> bool:
-    return _warm_state == "ready"
+_WARMUP = DeviceWarmup("absdomain-kb", _compile_smallest_bucket)
+#: compile the smallest bucket synchronously (idempotent)
+warmup = _WARMUP.warmup
+ensure_warming = _WARMUP.ensure_warming
+interpreter_ready = _WARMUP.ready
 
 
 def should_use_device() -> bool:
     """Offload known-bits only on a real accelerator, once compiled."""
-    if _backend() == "cpu":
+    if not on_tpu():
         return False
     if not interpreter_ready():
         ensure_warming()
+        note_host_route("prefilter_warming")
         return False
     return True

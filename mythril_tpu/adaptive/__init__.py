@@ -1,7 +1,7 @@
 """Coverage-guided adaptive exploration (the observe→steer loop).
 
 PRs 13–18 built the observability stack — the exploration ledger's
-coverage bitmaps and termination taxonomy, the static reachable-edge
+coverage bitmaps and termination classification, the static reachable-edge
 oracle, solver-hotspot attribution — but nothing acted on any of it.
 This package closes the loop:
 

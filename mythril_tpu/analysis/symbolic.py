@@ -81,17 +81,14 @@ class SymExecWrapper:
 
         # forced device backend: compile the probe interpreter BEFORE engine
         # timers start (the one-time XLA compile must not eat the creation-tx
-        # timeout); best-effort — failure degrades to the host path.  The
-        # "auto" backend instead warms lazily in the background when a query
-        # first crosses the device break-even (solver._try_compile_device)
-        # and uses the host path until ready.
+        # timeout); a failed compile fails the analysis.  The "auto" backend
+        # instead warms lazily in the background when a query first crosses
+        # the device break-even (solver._try_compile_device) and uses the
+        # host path until ready.
         if args.probe_backend == "jax":
-            try:
-                from mythril_tpu.ops import tape_vm
+            from mythril_tpu.ops import tape_vm
 
-                tape_vm.warmup()
-            except Exception as e:
-                log.warning("device probe warmup failed (host fallback): %s", e)
+            tape_vm.warmup()
 
         # seed world state with the actor accounts (reference symbolic.py:100-117)
         world_state = WorldState()

@@ -247,15 +247,7 @@ class LaserEVM:
             # first drain past a short host warmup (loop below) so the
             # engine's throughput bail compares segment rates against the
             # MEASURED host stepping rate instead of a blind floor.
-            try:
-                from mythril_tpu.frontier import FrontierEngine
-
-                FrontierEngine(self).drain_work_list()
-            except Exception as e:  # graceful degradation, never lose a run
-                log.warning(
-                    "frontier engine failed; host engine continues: %s",
-                    e, exc_info=True,
-                )
+            self._drain_frontier()
         start = time.perf_counter()
         deadline = (
             start + self.create_timeout
@@ -336,30 +328,36 @@ class LaserEVM:
             ):
                 first_drain_attempted = True
                 pending_seeds = 0
-                try:
-                    from mythril_tpu.frontier import FrontierEngine
-
-                    executed = FrontierEngine(self).drain_work_list()
-                    # three consecutive no-op attempts mean the engine's
-                    # gates (width / verdict memos) reject this workload:
-                    # stop paying the per-attempt work-list rescan for the
-                    # rest of this transaction
-                    zero_drains = zero_drains + 1 if executed == 0 else 0
-                    if zero_drains >= 3:
-                        frontier_live = False
-                        # never shrink below the last re-arm threshold, or
-                        # a work list oscillating around it would flap the
-                        # device on/off at a constant width forever
-                        rearm_width = max(
-                            2 * len(self.work_list), 32, rearm_width
-                        )
-                except Exception as e:  # graceful degradation
-                    log.warning(
-                        "nested frontier drain failed; host continues: %s", e,
-                        exc_info=True,
+                executed = self._drain_frontier()
+                # three consecutive no-op attempts mean the engine's
+                # gates (width / verdict memos) reject this workload:
+                # stop paying the per-attempt work-list rescan for the
+                # rest of this transaction
+                zero_drains = zero_drains + 1 if executed == 0 else 0
+                if zero_drains >= 3:
+                    frontier_live = False
+                    # never shrink below the last re-arm threshold, or
+                    # a work list oscillating around it would flap the
+                    # device on/off at a constant width forever
+                    rearm_width = max(
+                        2 * len(self.work_list), 32, rearm_width
                     )
         self._fire("stop_exec")
         return final_states if track_gas else None
+
+    def _drain_frontier(self) -> int:
+        """One frontier drain of the work list.  A failure fails the
+        analysis as a ``DeviceFailure``; the host never silently takes
+        over the paths the device was meant to run."""
+        from mythril_tpu.frontier import FrontierEngine
+        from mythril_tpu.support.device import DeviceFailure
+
+        try:
+            return FrontierEngine(self).drain_work_list()
+        except DeviceFailure:
+            raise
+        except Exception as e:
+            raise DeviceFailure(f"frontier drain failed: {e!r}") from e
 
     def _coverage_target_stop(self) -> bool:
         """True when the adaptive controller's --coverage-target verdict

@@ -37,6 +37,7 @@ from typing import List, Optional, Sequence, Tuple
 
 from mythril_tpu.native.bitblast import Unsupported
 from mythril_tpu.smt.terms import Term
+from mythril_tpu.support.device import DeviceFailure
 
 __all__ = ["decide", "decide_batch", "configure", "reset_state"]
 
@@ -116,7 +117,7 @@ def _validate(conjuncts, blasted, assign_row):
 def decide_batch(
     conjunct_sets: Sequence[Sequence[Term]],
 ) -> List[Tuple[str, Optional[object]]]:
-    """One (status, model) per row; never raises.
+    """One (status, model) per row; raises only ``DeviceFailure``.
 
     Status is ``"sat"`` (model is a validated ``Assignment``),
     ``"unsat"`` (exact), or ``"unknown"`` (fall through — admission
@@ -188,13 +189,16 @@ def decide_batch(
             [(blasted[i].clauses, blasted[i].dec_vars) for i in idx],
             n_vars)
         t0 = time.perf_counter()
-        try:
-            if device.should_use_device():
+        if device.should_use_device():
+            try:
                 status, assign = device.run_device(plane, _config["iters"])
-            else:
+            except Exception as e:
+                raise DeviceFailure(f"devsolver kernel: {e!r}") from e
+        else:
+            try:
                 status, assign = kernel.run_host(plane, _config["iters"])
-        except Exception:
-            status, assign = None, None
+            except Exception:
+                status, assign = None, None
         c_wall.inc(round(time.perf_counter() - t0, 6))
 
         for qi, i in enumerate(idx):

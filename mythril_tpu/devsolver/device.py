@@ -16,19 +16,13 @@ host twin (or the exact tiers) would have answered sooner.
 
 from __future__ import annotations
 
-import logging
-import threading
 from typing import Tuple
 
 import numpy as np
 
 from mythril_tpu.devsolver import kernel
 from mythril_tpu.devsolver.kernel import RUNNING, UNKNOWN_Q, Plane
-
-log = logging.getLogger(__name__)
-
-_warm_lock = threading.Lock()
-_warm_state = "cold"  # cold -> warming -> ready
+from mythril_tpu.support.device import DeviceWarmup, note_host_route, on_tpu
 
 _jitted = None
 
@@ -99,68 +93,25 @@ def run_device(plane: Plane, max_iters: int
 # ---------------------------------------------------------------------------
 
 
-def _backend() -> str:
-    try:
-        import jax
-
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
+def _compile_smallest_bucket() -> None:
+    # smallest buckets: 1 query, 1 real clause, 3 variables
+    plane = kernel.pack_plane([([[4]], [2])], n_vars=3)
+    run_device(plane, 8)
 
 
-def _compile_claimed() -> None:
-    global _warm_state
-    try:
-        # smallest buckets: 1 query, 1 real clause, 3 variables
-        plane = kernel.pack_plane([([[4]], [2])], n_vars=3)
-        run_device(plane, 8)
-        with _warm_lock:
-            _warm_state = "ready"
-    except BaseException:
-        with _warm_lock:
-            _warm_state = "cold"  # allow a later retry
-        raise
-
-
-def warmup() -> None:
-    """Compile the smallest bucket synchronously (idempotent)."""
-    global _warm_state
-    with _warm_lock:
-        if _warm_state != "cold":
-            return
-        _warm_state = "warming"
-    _compile_claimed()
-
-
-def ensure_warming() -> None:
-    """Kick the compile on a background thread (claimed under the lock,
-    so back-to-back callers never spawn duplicate compile threads)."""
-    global _warm_state
-    with _warm_lock:
-        if _warm_state != "cold":
-            return
-        _warm_state = "warming"
-
-    def _guarded():
-        try:
-            _compile_claimed()
-        except Exception:
-            log.debug("devsolver device warmup failed; host twin stays",
-                      exc_info=True)
-
-    threading.Thread(target=_guarded, daemon=False,
-                     name="devsolver-warmup").start()
-
-
-def interpreter_ready() -> bool:
-    return _warm_state == "ready"
+_WARMUP = DeviceWarmup("devsolver", _compile_smallest_bucket)
+#: compile the smallest bucket synchronously (idempotent)
+warmup = _WARMUP.warmup
+ensure_warming = _WARMUP.ensure_warming
+interpreter_ready = _WARMUP.ready
 
 
 def should_use_device() -> bool:
     """Offload the search only on a real accelerator, once compiled."""
-    if _backend() == "cpu":
+    if not on_tpu():
         return False
     if not interpreter_ready():
         ensure_warming()
+        note_host_route("devsolver_warming")
         return False
     return True

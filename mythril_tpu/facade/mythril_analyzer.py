@@ -16,6 +16,7 @@ from mythril_tpu.analysis.report import Issue, Report
 from mythril_tpu.analysis.security import fire_lasers, retrieve_callback_issues
 from mythril_tpu.analysis.symbolic import SymExecWrapper
 from mythril_tpu.smt.solver import SolverStatistics
+from mythril_tpu.support.device import DeviceFailure
 from mythril_tpu.support.support_args import args
 
 log = logging.getLogger(__name__)
@@ -63,9 +64,8 @@ class AnalyzerArgs:
     coverage_target: Optional[float] = None
     solver_workers: int = 2
     harvest_workers: int = 4
-    compile_cache_dir: Optional[str] = None
-    # one directory pinning BOTH persistent caches (querycache/ + xla/);
-    # explicit query_cache_dir / compile_cache_dir win over the derivation
+    # one directory for the persistent query cache (querycache/); an
+    # explicit query_cache_dir wins over the derivation
     cache_root: Optional[str] = None
     heartbeat_out: Optional[str] = None
     heartbeat_interval: float = 0.5
@@ -203,6 +203,8 @@ class MythrilAnalyzer:
                 ]
                 if args.frontier:
                     execution_info.append(FrontierStatsInfo())
+            except DeviceFailure:
+                raise  # a device failure fails the analysis, never partial
             except KeyboardInterrupt:
                 log.critical("keyboard interrupt: saving partial results")
                 issues = retrieve_callback_issues(modules or self.cmd_args.modules)

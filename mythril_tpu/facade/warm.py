@@ -11,7 +11,7 @@ between batches.  This module is the shared half:
   ``MythrilAnalyzer.__init__`` so the daemon and the one-shot facade run
   the identical configuration path (including ``--cache-root``
   derivation and cache arming).
-* ``resolve_cache_root`` — one directory pins both persistent caches.
+* ``resolve_cache_root`` — one directory pins the persistent query cache.
 * ``reset_analysis_scope`` — the scope sweep that makes each service
   batch behave like a fresh process: non-persistent metrics, detector
   issue lists, and the process-wide (address, bytecode_hash) detection
@@ -32,7 +32,7 @@ from __future__ import annotations
 
 import contextlib
 import os
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional
 
 from mythril_tpu.support.support_args import args
 
@@ -44,18 +44,16 @@ __all__ = [
 ]
 
 
-def resolve_cache_root(cache_root: Optional[str]) -> Tuple[Optional[str], Optional[str]]:
-    """Map ``--cache-root DIR`` to ``(query_cache_dir, compile_cache_dir)``.
-
-    One directory configures all service persistence: the SMT query
-    cache lands under ``DIR/querycache`` and the XLA compilation cache
-    under ``DIR/xla``.  Explicit ``--query-cache-dir`` /
-    ``--compile-cache-dir`` flags win over the derived paths.
+def resolve_cache_root(cache_root: Optional[str]) -> Optional[str]:
+    """Map ``--cache-root DIR`` to the query cache directory
+    ``DIR/querycache``; an explicit ``--query-cache-dir`` wins over it.
+    The XLA compilation cache is not placed here: it follows
+    ``JAX_COMPILATION_CACHE_DIR`` (``mythril_tpu.arm_compile_cache``).
     """
     if not cache_root:
-        return None, None
+        return None
     root = os.path.abspath(os.path.expanduser(cache_root))
-    return os.path.join(root, "querycache"), os.path.join(root, "xla")
+    return os.path.join(root, "querycache")
 
 
 def apply_analyzer_args(cmd_args) -> None:
@@ -121,25 +119,18 @@ def apply_analyzer_args(cmd_args) -> None:
     args.flight_recorder = getattr(cmd_args, "flight_recorder", None)
     args.history_dir = getattr(cmd_args, "history_dir", None)
     args.watchdog_deadline = getattr(cmd_args, "watchdog_deadline", None)
-    # --cache-root pins both persistent caches under one directory;
-    # explicit per-cache flags win over the derived paths
+    # --cache-root pins the persistent query cache; an explicit
+    # --query-cache-dir wins over the derived path
     args.cache_root = getattr(cmd_args, "cache_root", None)
-    derived_qc, derived_xla = resolve_cache_root(args.cache_root)
     args.query_cache_dir = (
-        getattr(cmd_args, "query_cache_dir", None) or derived_qc
-    )
-    args.compile_cache_dir = (
-        getattr(cmd_args, "compile_cache_dir", None) or derived_xla
+        getattr(cmd_args, "query_cache_dir", None)
+        or resolve_cache_root(args.cache_root)
     )
     from mythril_tpu.querycache import configure as _configure_query_cache
 
     _configure_query_cache(
         enabled=args.query_cache, cache_dir=args.query_cache_dir
     )
-    if args.compile_cache_dir:
-        from mythril_tpu import enable_persistent_compilation_cache
-
-        enable_persistent_compilation_cache(args.compile_cache_dir)
 
 
 class WorkerContext:

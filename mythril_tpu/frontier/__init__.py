@@ -29,8 +29,7 @@ __all__ = ["FrontierEngine"]
 def __getattr__(name: str):
     # lazy: detection modules import frontier.taint (jax-free) at load time;
     # an eager engine import here would pull step -> jax into every detector
-    # load and defeat svm.py's deliberately-lazy FrontierEngine import and
-    # its graceful degradation when jax is unavailable
+    # load and defeat svm.py's deliberately-lazy FrontierEngine import
     if name == "FrontierEngine":
         from mythril_tpu.frontier.engine import FrontierEngine
 

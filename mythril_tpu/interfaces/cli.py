@@ -272,21 +272,12 @@ def _add_analysis_options(parser) -> None:
         "0 runs the serial harvest",
     )
     group.add_argument(
-        "--compile-cache-dir",
-        metavar="DIR",
-        help="persist XLA compilations in DIR and reuse them across "
-        "processes (skips segment recompiles on warm starts); default ON "
-        "under ~/.cache/mythril-tpu/xla — set the "
-        "MYTHRIL_TPU_COMPILATION_CACHE env var to 0/off to disable, or "
-        "to a path to relocate",
-    )
-    group.add_argument(
         "--cache-root",
         metavar="DIR",
-        help="pin BOTH persistent caches under one directory: SMT query "
-        "cache in DIR/querycache, XLA compilation cache in DIR/xla (one "
-        "flag for service deployments); explicit --query-cache-dir / "
-        "--compile-cache-dir win over the derived paths",
+        help="persist the SMT query cache in DIR/querycache (one flag "
+        "for service deployments); an explicit --query-cache-dir wins. "
+        "XLA programs are cached where JAX_COMPILATION_CACHE_DIR says, "
+        "else in <checkout>/.jax_cache",
     )
     group.add_argument(
         "--no-staticpass",
@@ -843,7 +834,6 @@ def _build_analyzer(parsed, query_signature: bool = False):
         coverage_target=getattr(parsed, "coverage_target", None),
         solver_workers=getattr(parsed, "solver_workers", 2),
         harvest_workers=getattr(parsed, "harvest_workers", 4),
-        compile_cache_dir=getattr(parsed, "compile_cache_dir", None),
         cache_root=getattr(parsed, "cache_root", None),
         heartbeat_out=getattr(parsed, "heartbeat_out", None),
         heartbeat_interval=getattr(parsed, "heartbeat_interval", 0.5),
@@ -1424,6 +1414,13 @@ def execute_command(parsed) -> None:
     raise CriticalError(f"unknown command {command}")
 
 
+#: commands that run the engine (and so compile XLA programs); the client
+#: commands (submit, top, health, ...) stay jax-free
+_ENGINE_COMMANDS = frozenset(
+    {"analyze", "a", "serve", "concolic", "c", "safe-functions"}
+)
+
+
 def main() -> None:
     parser = create_parser()
     parsed = parser.parse_args()
@@ -1436,9 +1433,16 @@ def main() -> None:
     _set_logging(getattr(parsed, "v", 2))
     from mythril_tpu.exceptions import MythrilBaseException
 
+    if parsed.command in _ENGINE_COMMANDS:
+        import mythril_tpu
+
+        mythril_tpu.arm_compile_cache()
+
+    from mythril_tpu.support.device import DeviceFailure
+
     try:
         execute_command(parsed)
-    except MythrilBaseException as e:
+    except (MythrilBaseException, DeviceFailure) as e:
         exit_with_error(getattr(parsed, "outform", "text"), str(e))
 
 

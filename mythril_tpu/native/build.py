@@ -1,14 +1,17 @@
 """Lazy in-tree build of the native library (g++ -O2 -shared -fPIC).
 
 No pybind11 in this environment, so the boundary is a C ABI loaded with
-ctypes.  The .so is cached next to the sources and rebuilt whenever a source
-file is newer; concurrent builds are serialized with an exclusive lock so
+ctypes.  The .so is keyed on a hash of the committed sources' CONTENTS and
+the compile command (``_libmythril_native.<hash>.so``), never on mtimes: a
+tree copied with a stale library can only ever load the build its own
+sources produce.  Concurrent builds are serialized with an exclusive lock so
 parallel pytest workers don't race the compiler.
 """
 
 from __future__ import annotations
 
 import fcntl
+import hashlib
 import logging
 import os
 import shutil
@@ -18,50 +21,48 @@ from typing import Optional
 
 log = logging.getLogger(__name__)
 
-_SRC_DIR = Path(__file__).parent / "src"
-_LIB_PATH = Path(__file__).parent / "_libmythril_native.so"
+_DIR = Path(__file__).parent
+_SRC_DIR = _DIR / "src"
 _SOURCES = ["bitblast.cpp", "keccak.cpp"]
+_FLAGS = ["-O2", "-std=c++17", "-shared", "-fPIC"]
+
+
+def _lib_path() -> Path:
+    h = hashlib.sha256(" ".join(_FLAGS).encode())
+    for name in _SOURCES:
+        h.update(name.encode())
+        h.update((_SRC_DIR / name).read_bytes())
+    return _DIR / f"_libmythril_native.{h.hexdigest()[:16]}.so"
+
+
+def build_library() -> Path:
+    """Path to the library built from the committed sources, building it
+    if needed; raises when it cannot be built."""
+    lib = _lib_path()
+    if lib.exists():
+        return lib
+    gxx = shutil.which("g++") or shutil.which("c++")
+    if gxx is None:
+        raise RuntimeError("no C++ compiler on PATH")
+    with open(_DIR / "_libmythril_native.lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if lib.exists():
+            return lib
+        tmp = lib.with_suffix(".so.tmp")
+        cmd = [gxx, *_FLAGS, "-o", str(tmp),
+               *[str(_SRC_DIR / s) for s in _SOURCES]]
+        proc = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
+        if proc.returncode != 0:
+            raise RuntimeError(f"native build failed:\n{proc.stderr[-2000:]}")
+        os.replace(tmp, lib)
+        return lib
 
 
 def library_path() -> Optional[Path]:
-    """Path to the built library, building it if needed; None if impossible."""
-    sources = [_SRC_DIR / s for s in _SOURCES if (_SRC_DIR / s).exists()]
-    if not sources:
-        return None
-    if _LIB_PATH.exists() and all(
-        _LIB_PATH.stat().st_mtime >= s.stat().st_mtime for s in sources
-    ):
-        return _LIB_PATH
-    gxx = shutil.which("g++") or shutil.which("c++")
-    if gxx is None:
-        log.debug("no C++ compiler on PATH; native tier disabled")
-        return None
-    lock_path = _LIB_PATH.with_suffix(".lock")
+    """``build_library()``, or None when it cannot be built (the solver
+    tiers that use it then stay off; ``chip_smoke.py`` fails instead)."""
     try:
-        with open(lock_path, "w") as lock:
-            fcntl.flock(lock, fcntl.LOCK_EX)
-            if _LIB_PATH.exists() and all(
-                _LIB_PATH.stat().st_mtime >= s.stat().st_mtime for s in sources
-            ):
-                return _LIB_PATH
-            tmp = _LIB_PATH.with_suffix(".so.tmp")
-            cmd = [
-                gxx,
-                "-O2",
-                "-std=c++17",
-                "-shared",
-                "-fPIC",
-                "-o",
-                str(tmp),
-                *[str(s) for s in sources],
-            ]
-            proc = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
-            if proc.returncode != 0:
-                log.warning("native build failed:\n%s", proc.stderr[-2000:])
-                return None
-            os.replace(tmp, _LIB_PATH)
-            return _LIB_PATH
-    except Exception as e:  # hung compiler, lock failure, ... — callers
-        # treat library_path()/available() as non-throwing and fall back
-        log.debug("native build unavailable: %s", e)
+        return build_library()
+    except (OSError, RuntimeError, subprocess.SubprocessError) as e:
+        log.warning("native library unavailable: %s", e)
         return None

@@ -44,7 +44,7 @@ __all__ = [
     "get_exploration_ledger",
 ]
 
-#: The termination taxonomy.  Exactly one class per terminated path:
+#: The termination classification.  Exactly one class per terminated path:
 #:   completed      — ran to a terminal halt (STOP/RETURN/REVERT/
 #:                    SELFDESTRUCT/INVALID) or the host replay ended it
 #:   prefilter_killed — the abstract interval/known-bits pass proved the

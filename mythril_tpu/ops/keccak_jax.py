@@ -97,12 +97,11 @@ def keccak_f1600(state: jnp.ndarray) -> jnp.ndarray:
     portable path runs the rounds under ``lax.scan`` so the compiled graph
     holds ONE round body — a fully unrolled version takes minutes of XLA
     compile time."""
+    from mythril_tpu.support.device import on_tpu
     from mythril_tpu.support.support_args import args
 
     backend = getattr(args, "keccak_backend", "auto")
-    if backend == "pallas" or (
-        backend == "auto" and jax.default_backend() == "tpu"
-    ):
+    if backend == "pallas" or (backend == "auto" and on_tpu()):
         from mythril_tpu.ops import keccak_pallas
 
         return keccak_pallas.keccak_f1600(state)
@@ -124,12 +123,14 @@ def _gather_bytes(data: jnp.ndarray, width: int) -> list:
     return out
 
 
-def keccak256(data: jnp.ndarray, width: int) -> jnp.ndarray:
+def keccak256(data: jnp.ndarray, width: int, permute=None) -> jnp.ndarray:
     """keccak-256 of the big-endian byte serialization of a bitvector.
 
     ``data``: [..., nlimbs(width)] uint32; ``width`` must be a multiple of 8
     (the term layer guarantees byte-width hash inputs).  Returns [..., 16]
-    limbs (a 256-bit word)."""
+    limbs (a 256-bit word).  ``permute`` pins the keccak-f[1600]
+    implementation (default: ``keccak_f1600``'s backend choice)."""
+    permute = permute or keccak_f1600
     assert width % 8 == 0, "keccak input must be byte-aligned"
     msg = _gather_bytes(data, width)
     n = len(msg)
@@ -155,7 +156,7 @@ def keccak256(data: jnp.ndarray, width: int) -> jnp.ndarray:
             lanes.append(jnp.stack(limbs, axis=-1))
         absorb = jnp.stack(lanes, axis=-2)  # [..., 17, 4]
         state = state.at[..., :17, :].set(state[..., :17, :] ^ absorb)
-        state = keccak_f1600(state)
+        state = permute(state)
 
     # squeeze 32 bytes = lanes 0..3; output word is big-endian bytes
     out_bytes = []  # big-endian byte list, most significant first

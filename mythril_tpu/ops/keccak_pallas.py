@@ -28,11 +28,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-import mythril_tpu
 from mythril_tpu.ops.bitvec import LIMB_BITS, LIMB_MASK
 from mythril_tpu.ops.keccak_jax import _PI_ROT, _PI_SRC, _RC_LIMBS
-
-mythril_tpu.enable_persistent_compilation_cache()
 
 # Row index tables for the flattened (100 = lane*4 + limb, B) layout.
 # rho+pi as one fused static row gather: out_row[dst*4 + j] combines

@@ -91,9 +91,6 @@ log = logging.getLogger(__name__)
 
 __all__ = ["AnalysisService", "ServiceConfig"]
 
-#: minimal STOP contract used to pull heavy imports during warmup
-_WARMUP_CODE = bytes.fromhex("00")
-
 #: bound on the request-id -> flight registry backing the poll API
 _RID_REGISTRY_CAP = 4096
 
@@ -183,6 +180,7 @@ class AnalysisService:
         self._jobs_lock = threading.Lock()
         self._stop = threading.Event()
         self._warm_ready = threading.Event()
+        self._startup_error: Optional[str] = None  # inline warmup failure
         self._draining = False
         self._started = False
         # request-id -> (key, flight-or-None): the poll/long-poll path
@@ -304,7 +302,17 @@ class AnalysisService:
         if self.pooled:
             from mythril_tpu.service.pool import WorkerPool
             from mythril_tpu.service.worker import worker_config
+            from mythril_tpu.support.device import local_tpu_chips
 
+            # one process per chip: each worker initialises JAX on its own
+            # chip, and this parent never initialises a backend at all
+            chips = local_tpu_chips()
+            if chips and self.config.workers > chips:
+                raise RuntimeError(
+                    f"--workers {self.config.workers} exceeds the {chips} "
+                    f"TPU chip(s) on this host: each worker process owns one "
+                    f"chip (serve a one-chip host with --workers 1)"
+                )
             self._pool = WorkerPool(
                 self.config.workers,
                 worker_config(self.config),
@@ -330,13 +338,24 @@ class AnalysisService:
             self._start_watchtower()
         return self
 
+    @property
+    def startup_error(self) -> Optional[str]:
+        """Why startup warmup failed (inline, or any pool worker), else None."""
+        if self._pool is not None:
+            return self._pool.startup_error
+        return self._startup_error
+
     def wait_warm(self, timeout: Optional[float] = None) -> bool:
         """Block until startup warmup has finished (immediately true when
         ``warmup=False`` and inline; in pool mode, until every worker
         process has reported ready).  Load generators use this so
         measured windows start from a warm process, matching the
-        service's steady state."""
-        return self._warm_ready.wait(timeout)
+        service's steady state.  A failed warmup raises: the service
+        did not start."""
+        warm = self._warm_ready.wait(timeout)
+        if self.startup_error:
+            raise RuntimeError(f"service warmup failed: {self.startup_error}")
+        return warm
 
     def stop(self, drain: bool = True, timeout: Optional[float] = None) -> bool:
         """Stop the worker(s); with ``drain`` (the SIGTERM path) finish
@@ -490,21 +509,18 @@ class AnalysisService:
         )).configure()
 
     def _warmup(self) -> None:
-        """Pull heavy imports + solver setup with a minimal contract so
-        the first real request pays dispatch, not process warmup."""
-        from mythril_tpu.analysis.cooperative import run_cooperative_batch
+        """The inline worker's startup warmup (``worker.run_warmup``); a
+        failure is kept in ``startup_error`` and the service never serves."""
+        from mythril_tpu.service.worker import run_warmup
 
         t0 = time.perf_counter()
         try:
             with _otrace.span("service.warmup", cat="service"):
-                run_cooperative_batch(
-                    [("warmup", _WARMUP_CODE)],
-                    transaction_count=1,
-                    execution_timeout=5,
-                    isolate_errors=True,
-                )
-        except Exception:
-            log.exception("service warmup failed; continuing cold")
+                run_warmup()
+        except Exception as e:
+            log.exception("service warmup failed; the service does not start")
+            self._startup_error = repr(e)
+            return
         self._scope_reset()
         log.info("service warmup done in %.2fs", time.perf_counter() - t0)
 
@@ -522,7 +538,7 @@ class AnalysisService:
 
         Raises ``AdmissionRejected`` when the scheduling policy refuses
         the submission (tenant quota, load shed)."""
-        if self._draining or not self._started:
+        if self._draining or not self._started or self.startup_error:
             raise RuntimeError("service is not accepting submissions")
         if tier not in (TIER_BATCH, TIER_INTERACTIVE):
             raise ValueError(f"unknown tier {tier!r}")
@@ -844,6 +860,8 @@ class AnalysisService:
         if self.config.warmup:
             self._warmup()
         self._warm_ready.set()
+        if self._startup_error:
+            return
         cfg = self.config
         while True:
             if not self.admission.wait_for_pending(timeout=0.1):

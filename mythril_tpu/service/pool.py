@@ -127,6 +127,9 @@ class WorkerPool:
         self._stopping = False
         self._ready_once: set = set()
         self._all_ready = threading.Event()
+        #: first worker startup failure (configure or warmup); wakes
+        #: ``wait_ready`` and fails the daemon's startup
+        self.startup_error: Optional[str] = None
         self.handles: List[WorkerHandle] = [
             WorkerHandle(i, config, self.event_q, self._mp) for i in range(n)
         ]
@@ -285,6 +288,12 @@ class WorkerPool:
         elif kind == "stopped":
             with self._lock:
                 handle.state = STOPPING
+        elif kind == "failed":
+            with self._lock:
+                handle.state = DEAD  # never respawned: startup failed
+                self.startup_error = self.startup_error or (
+                    f"worker {wid}: {msg[2]}")
+            self._all_ready.set()
         try:
             self.on_event(msg)
         except Exception:

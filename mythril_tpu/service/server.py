@@ -253,6 +253,12 @@ def run_server(
     in-flight flight runs to its terminal event, then the socket closes.
     """
     server = AnalysisServer(config, host=host, port=port).start()
+    if server.service.config.warmup:
+        try:
+            server.service.wait_warm()  # a failed warmup fails startup
+        except RuntimeError:
+            server.stop(drain=False)
+            raise
     stop = threading.Event()
 
     def _on_signal(signum, _frame):

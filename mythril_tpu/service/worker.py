@@ -30,6 +30,7 @@ worker -> daemon, over the pool's shared event queue (every kind keeps
 the worker id at index 1 — the pool's event pump keys liveness on it)::
 
     ("ready",   worker_id, pid)                                # warm, idle
+    ("failed",  worker_id, error_repr)            # startup failed; exits
     ("issue",   worker_id, job_id, codehash, wire, source)     # streamed
     ("done",    worker_id, job_id, payload)                    # terminal
     ("telemetry", worker_id, payload)              # fleet delta snapshot
@@ -82,6 +83,36 @@ DEFAULT_FLUSH_INTERVAL_S = 0.5
 _WARMUP_CODE = bytes.fromhex("00")
 
 
+def run_warmup() -> None:
+    """Pull heavy imports + solver setup with a minimal contract so the
+    first real request pays dispatch, not process warmup.  A failure
+    raises: a service whose warmup fails does not start."""
+    from mythril_tpu.analysis.cooperative import run_cooperative_batch
+
+    run_cooperative_batch(
+        [("warmup", _WARMUP_CODE)],
+        transaction_count=1,
+        execution_timeout=5,
+        isolate_errors=False,
+    )
+
+
+def pin_tpu_chip(worker_id: int) -> None:
+    """Give this worker process the ``worker_id``-th chip this host lets
+    the service use and no other, before its JAX backend initialises (one
+    process per chip)."""
+    visible = os.environ.get("TPU_VISIBLE_CHIPS")
+    chip = visible.split(",")[worker_id] if visible else str(worker_id)
+    os.environ.update({
+        "TPU_VISIBLE_CHIPS": chip,
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_PORT": str(8476 + worker_id),
+        "TPU_MESH_CONTROLLER_PORT": str(8476 + worker_id),
+        "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{8476 + worker_id}",
+    })
+
+
 def worker_config(service_config) -> Dict[str, Any]:
     """Picklable worker-process configuration from a ``ServiceConfig``.
 
@@ -89,9 +120,12 @@ def worker_config(service_config) -> Dict[str, Any]:
     the same ``apply_analyzer_args`` path the daemon's inline worker
     uses, so an N-worker pool and a solo run configure identically.
     """
+    from mythril_tpu.support.device import local_tpu_chips
+
     opts = service_config.default_options
     return {
         "options": opts.to_dict(),
+        "pin_tpu_chips": local_tpu_chips() > 0,
         "frontier": service_config.frontier,
         "cache_root": service_config.cache_root,
         "warmup": service_config.warmup,
@@ -371,24 +405,20 @@ def worker_main(worker_id: int, config: Dict[str, Any],
     if config.get("trace"):
         get_tracer().enabled = True
     publisher = FleetPublisher(worker_id)
+    if config.get("pin_tpu_chips"):
+        pin_tpu_chip(worker_id)
     try:
+        import mythril_tpu
+
+        mythril_tpu.arm_compile_cache()
         ctx = _make_context(config)
         if config.get("warmup", False):
-            from mythril_tpu.analysis.cooperative import run_cooperative_batch
-
-            try:
-                run_cooperative_batch(
-                    [("warmup", _WARMUP_CODE)],
-                    transaction_count=1,
-                    execution_timeout=5,
-                    isolate_errors=True,
-                )
-            except Exception:
-                log.exception("worker %d warmup failed; continuing cold",
-                              worker_id)
+            run_warmup()
             ctx.reset_scope()
-    except Exception:
-        log.exception("worker %d failed to configure; exiting", worker_id)
+    except Exception as e:
+        # the daemon fails startup on this event instead of respawning
+        log.exception("worker %d failed to start; exiting", worker_id)
+        event_q.put(("failed", worker_id, repr(e)))
         return
     # heartbeat runs here too — worker arena/queue-depth gauges exist in
     # the worker's registry and reach the daemon as fleet gauge samples

@@ -99,3 +99,35 @@ def test_safe_functions():
         "safe-functions", "-f", str(KILL_SIMPLE), "--bin-runtime"
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+def test_frontier_failure_fails_analyze(monkeypatch, capsys):
+    """An exception inside the frontier segment fails ``myth analyze
+    --frontier`` (non-zero exit); the host engine never silently takes
+    over the paths the device was meant to run."""
+    from mythril_tpu.frontier import engine
+    from mythril_tpu.interfaces import cli
+    from mythril_tpu.support.support_args import args
+
+    def broken_segment(*_a, **_k):
+        def segment(*_args):
+            raise RuntimeError("injected segment failure")
+        return segment
+
+    monkeypatch.setattr(engine, "cached_segment", broken_segment)
+    saved = dict(vars(args))
+    monkeypatch.setattr(sys, "argv", [
+        "myth", "analyze", "-f", str(KILL_SIMPLE), "--bin-runtime",
+        "-t", "1", "-m", "AccidentallyKillable", "--frontier",
+        "--frontier-force", "-o", "jsonv2",
+    ])
+    try:
+        with pytest.raises(SystemExit) as exc:
+            cli.main()
+    finally:
+        vars(args).clear()
+        vars(args).update(saved)
+    assert exc.value.code != 0
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert out["success"] is False
+    assert "injected segment failure" in out["error"]

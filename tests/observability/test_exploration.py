@@ -50,7 +50,7 @@ def test_every_class_is_stampable():
     assert all(n == 1 for n in led.terminated().values())
 
 
-def test_verdict_class_maps_into_taxonomy():
+def test_verdict_class_maps_into_classification():
     assert set(VERDICT_CLASS.values()) <= set(TERM_CLASSES)
     assert VERDICT_CLASS["unsat"] == "solver_unsat"
     assert VERDICT_CLASS["unknown"] == "solver_timeout_unknown"

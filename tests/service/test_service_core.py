@@ -207,7 +207,7 @@ def test_stop_drains_and_rejects_new_submissions(scoped_args):
         service.submit(KILL_SIMPLE_HEX, name="late")
 
 
-def test_cache_root_pins_both_caches(scoped_args, tmp_path):
+def test_cache_root_pins_query_cache_only(scoped_args, tmp_path):
     root = tmp_path / "svc-cache"
     service = AnalysisService(
         _config(probe=False, cache_root=str(root))
@@ -220,7 +220,9 @@ def test_cache_root_pins_both_caches(scoped_args, tmp_path):
     from mythril_tpu.support.support_args import args
 
     assert args.query_cache_dir == str(root / "querycache")
-    assert args.compile_cache_dir == str(root / "xla")
+    # the XLA cache follows JAX_COMPILATION_CACHE_DIR, never --cache-root
+    assert not hasattr(args, "compile_cache_dir")
+    assert not (root / "xla").exists()
     # the query cache persisted solved queries under the pinned root
     assert (root / "querycache").is_dir()
 
@@ -234,3 +236,28 @@ def test_wait_warm_and_stats(scoped_args):
         assert stats["service.queue_depth"] == 0
     finally:
         service.stop(drain=True, timeout=30)
+
+
+def test_failed_warmup_fails_startup(scoped_args, monkeypatch):
+    from mythril_tpu.service import worker
+
+    def boom():
+        raise RuntimeError("injected warmup failure")
+
+    monkeypatch.setattr(worker, "run_warmup", boom)
+    service = AnalysisService(_config(warmup=True)).start()
+    try:
+        with pytest.raises(RuntimeError, match="warmup failed"):
+            service.wait_warm(timeout=60)
+        with pytest.raises(RuntimeError, match="not accepting"):
+            service.submit(KILL_SIMPLE_HEX, name="late")
+    finally:
+        service.stop(drain=False, timeout=10)
+
+
+def test_workers_above_chip_count_refused(scoped_args, monkeypatch):
+    from mythril_tpu.support import device
+
+    monkeypatch.setattr(device, "local_tpu_chips", lambda: 1)
+    with pytest.raises(RuntimeError, match="exceeds the 1 TPU chip"):
+        AnalysisService(_config(workers=2)).start()
