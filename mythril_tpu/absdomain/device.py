@@ -52,7 +52,10 @@ def _get_jitted():
         fn = domains.KB_KERNELS.get(opc, domains._kb_top)
         branches.append(lambda p, A, B, C, _fn=fn: _fn(jnp, p, A, B, C))
 
-    def _run(op, w, x0, x1, a0, a1, a2, wa, wb, wm, cl, okm, okv):
+    # the function's name is the XLA module's (jit_absdomain_known_bits),
+    # which is how a device trace tells this program apart
+    def absdomain_known_bits(op, w, x0, x1, a0, a1, a2, wa, wb, wm, cl,
+                             okm, okv):
         n, r = okm.shape[0], okm.shape[1]
         km0 = jnp.zeros((n, r, LIMBS), jnp.uint32)
         kv0 = jnp.zeros((n, r, LIMBS), jnp.uint32)
@@ -88,7 +91,7 @@ def _get_jitted():
         )
         return km_all, kv_all, refuted
 
-    _jitted = jax.jit(_run)
+    _jitted = jax.jit(absdomain_known_bits)
     return _jitted
 
 

@@ -13,6 +13,7 @@ import json
 import time
 from typing import Dict, List, Optional
 
+from mythril_tpu.observability.metrics import get_registry
 from mythril_tpu.support.support_utils import get_code_hash
 
 
@@ -57,6 +58,11 @@ class Issue:
         self.lineno = None
         self.source_mapping = None
         self.discovery_time = time.time() - StartTime().global_start_time
+        # the analysis's account keeps, per issue, the epoch stamp and the
+        # solver busy seconds spent before it was found
+        self.discovery_ns = time.time_ns()
+        self.discovery_solver_s = get_registry().counter(
+            "solver.solver_time_s", initial=0.0).value
         self.bytecode_hash = get_code_hash(bytecode) if bytecode is not None else ""
         self.transaction_sequence = transaction_sequence
         self.source_location = None
@@ -124,6 +130,7 @@ class Report:
         self.issues: Dict[bytes, Issue] = {}
         self.solc_version = ""
         self.meta: Dict = {}
+        self._account: Optional[Dict] = None
         self.source = SourceHolder()
         self.exceptions = exceptions or []
         self.execution_info = execution_info or []
@@ -149,6 +156,21 @@ class Report:
             ).encode()
         ).digest()
         self.issues[key] = issue
+
+    def close_account(self) -> Dict:
+        """Close the analysis's account in the metrics registry, once: its
+        epoch interval, analysis-scoped metrics, compile seconds, and per
+        issue the SWC id, pc, discovery stamp and solver seconds then."""
+        if self._account is None:
+            from mythril_tpu.observability import close_analysis_account
+
+            self._account = close_analysis_account([
+                {"swc_id": i.swc_id, "pc": i.address,
+                 "discovery_ns": i.discovery_ns,
+                 "solver_time_s": round(i.discovery_solver_s, 6)}
+                for i in self.issues.values()
+            ])
+        return self._account
 
     # -- renderers ----------------------------------------------------------
 
@@ -245,7 +267,10 @@ class Report:
         from mythril_tpu.observability.exploration import exploration_meta
         from mythril_tpu.observability.watchtower import health_meta
 
+        # closed first: closing sets the analysis's frontier.drain_s
+        account = self.close_account()
         meta["observability"] = observability_meta()
+        meta["account"] = account
         meta["prefilter"] = _prefilter_meta()
         meta["devsolver"] = _devsolver_meta()
         meta["exploration"] = exploration_meta()

@@ -44,7 +44,9 @@ def _get_jitted():
     def scatter_or(shape, qi, vi, mask):
         return jnp.zeros(shape, bool).at[qi, vi].max(mask)
 
-    def _run(lits, dec, n_vars_arr, max_iters):
+    # the function's name is the XLA module's (jit_devsolver_search),
+    # which is how a device trace tells this program apart
+    def devsolver_search(lits, dec, n_vars_arr, max_iters):
         qb = lits.shape[0]
         vb = n_vars_arr.shape[0]
         d = dec.shape[1]
@@ -72,7 +74,7 @@ def _get_jitted():
         status = jnp.where(status == RUNNING, jnp.int8(UNKNOWN_Q), status)
         return status, assign
 
-    _jitted = jax.jit(_run)
+    _jitted = jax.jit(devsolver_search)
     return _jitted
 
 

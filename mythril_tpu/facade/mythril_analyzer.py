@@ -165,6 +165,7 @@ class MythrilAnalyzer:
         )
         for issue in all_issues:
             report.append_issue(issue)
+        report.close_account()
         return report
 
     def _fire_lasers_loop(self, modules, benchmark_base):

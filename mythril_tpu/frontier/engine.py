@@ -1367,16 +1367,6 @@ class FrontierEngine:
             )
             _get_metrics().counter("frontier.opening_dispatches").inc()
             _WARM_PROGRAMS.add((caps, natural_bucket, visit_cap))
-            _devplane.harvest_analysis(
-                nat_segment,
-                lambda st_nat=st_nat, dev_arena=dev_arena,
-                arena_len=arena_len, nat_visited=nat_visited,
-                nat_code_dev=nat_code_dev, cfg0=cfg0: (
-                    push_state(st_nat), dev_arena, arena_len, nat_visited,
-                    nat_code_dev, cfg0,
-                ),
-                _devplane.bucket_tag(natural_bucket),
-            )
             st = st_p._replace(loops=np.ascontiguousarray(np.pad(
                 st_p.loops, ((0, 0), (0, loops_cap - nat_lc))
             )))
@@ -1514,17 +1504,6 @@ class FrontierEngine:
             _get_metrics().observe("frontier.segment_wall_s", seg_only)
             _devplane.observe_segment(seg_only, _devplane.bucket_tag(bucket))
             _WARM_PROGRAMS.add(program_key)  # a segment really dispatched
-            # compiled + persistently cached by the dispatch above: harvest
-            # cost/memory analysis once per executable, off-thread
-            _devplane.harvest_analysis(
-                segment,
-                lambda st_dev=st_dev, dev_arena=dev_arena,
-                arena_len=arena_len, visited=visited, code_dev=code_dev,
-                cfg=cfg: (
-                    st_dev, dev_arena, arena_len, visited, code_dev, cfg
-                ),
-                _devplane.bucket_tag(bucket),
-            )
 
             t_har = time.perf_counter()
             with _otrace.span("frontier.harvest", cat="frontier",
@@ -1928,8 +1907,11 @@ class FrontierEngine:
             kills = [False] * len(todo)
             if getattr(args, "prefilter", True):
                 from mythril_tpu.absdomain import prefilter_batch
+                from mythril_tpu.smt.solver import tier_done
 
+                t_tier = time.perf_counter()
                 kills = prefilter_batch([raws for _, _, _, raws in todo])
+                tier_done("prefilter", t_tier, sum(kills))
             for (slot, rec, n_cons, raws), killed in zip(todo, kills):
                 rec._submitted_at = n_cons
                 pipe.pool.submit(

@@ -928,11 +928,6 @@ class PipelinedRunner:
                 if nxt is not None:
                     reg.counter("pipeline.overlap_segments").inc()
                 _eng._WARM_PROGRAMS.add(self.program_key)
-                # the executable is compiled and persistently cached now:
-                # harvest its cost/memory analysis once, off-thread
-                _devplane.harvest_analysis(
-                    self.segment, lambda: full_args, _bucket_tag
-                )
 
                 if micro_pending and n_exec_host > 0:
                     t_mb = time.perf_counter()

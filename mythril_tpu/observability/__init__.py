@@ -12,7 +12,9 @@ Two pieces, both process-wide singletons:
   counters / gauges / histograms that absorbs the mutable-attribute
   telemetry style of ``FrontierStatistics`` and ``SolverStatistics``.
   Those classes remain as thin facades over the registry so existing
-  call sites and report-meta output are unchanged.
+  call sites and report-meta output are unchanged.  The registry also
+  keeps one account per analysis (``reset_analysis_metrics`` opens it,
+  ``close_analysis_account`` closes it when the report is built).
 
 The convenience re-exports below are the recommended import surface::
 
@@ -90,18 +92,22 @@ from mythril_tpu.observability.watchtower import (  # noqa: F401
 )
 
 
-def observability_meta() -> dict:
-    """Snapshot block embedded in report meta and BENCH rows."""
-    # Materialize the facade-backed metrics first so the snapshot always
-    # carries the full frontier.*/solver.* key set, even for runs where a
-    # stage never executed (e.g. narrow workloads that bail off-device).
+def _materialize_facades() -> None:
+    # the facade-backed metrics, so a snapshot always carries the full
+    # frontier.*/solver.* key set, even for runs where a stage never
+    # executed (e.g. narrow workloads that bail off-device)
     from mythril_tpu.frontier.stats import FrontierStatistics
     from mythril_tpu.querycache.cache import materialize_counters
     from mythril_tpu.smt.solver import SolverStatistics
 
     FrontierStatistics()._materialize()
-    SolverStatistics()
+    SolverStatistics().materialize()
     materialize_counters()
+
+
+def observability_meta() -> dict:
+    """Snapshot block embedded in report meta and BENCH rows."""
+    _materialize_facades()
     tracer = get_tracer()
     meta = {"metrics": get_registry().snapshot()}
     if tracer.enabled or len(tracer):
@@ -119,12 +125,38 @@ def reset_analysis_metrics() -> None:
     keeps across runs so a code that degenerated once is not re-probed —
     survive the sweep.  The exploration ledger's coverage bitmaps are
     swept with the same scope (its counters live in the registry and
-    reset with everything else).
+    reset with everything else).  It also opens the analysis's account
+    in the registry (``close_analysis_account`` closes it).
     """
     get_registry().reset()
+    get_registry().open_account()
     get_exploration_ledger().reset_scope()
     # the adaptive controller's plan cache / coverage history / latched
     # coverage-stop verdict all describe the scope being swept
     from mythril_tpu.adaptive import get_adaptive_controller
 
     get_adaptive_controller().reset_scope()
+
+
+def close_analysis_account(issues=()) -> dict:
+    """Close the running analysis's account when its report is built.
+
+    ``issues`` are per-issue records (SWC id, pc, discovery stamp, solver
+    seconds at discovery).  The drain runs from the passing of the
+    execution deadline (the execution timer's, which the frontier engine,
+    the pipeline and the host engine all check) to now; under
+    ``--trace-out`` it is also the span ``analysis.drain``.
+    """
+    import time
+
+    from mythril_tpu.support.time_handler import time_handler
+
+    _materialize_facades()
+    deadline = time_handler.deadline()
+    acct = get_registry().close_account(
+        issues, None if deadline is None else int(deadline * 1e9))
+    drain = acct["metrics"]["frontier.drain_s"]
+    if drain:
+        get_tracer().record_span("analysis.drain", "analysis",
+                                 time.perf_counter() - drain, drain)
+    return acct

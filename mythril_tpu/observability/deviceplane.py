@@ -25,14 +25,10 @@ into ordinary registry metrics:
   plus per-bucket ``..._sum{bucket=…}`` / ``..._count{bucket=…}``
   labeled series (the registry has no labeled-histogram kind; a
   sum/count pair per label is the standard Prometheus degradation).
-* ``harvest_analysis(fn, args_thunk, tag)`` runs the AOT
-  ``fn.lower(*args).compile()`` path once per executable in a daemon
-  thread and publishes ``Compiled.cost_analysis()`` /
-  ``memory_analysis()`` into ``device.flops_per_segment{bucket=…}`` and
-  ``device.hbm_bytes{bucket=…}`` gauges.  Both analyses may return
-  ``None``, partial dicts, or raise outright on CPU backends — absence
-  degrades to ``device.analysis_unavailable{reason=…}`` counters, never
-  a crash and never a zero that reads as "free".
+* With the tracer on, every backend compile is also recorded as an
+  ``xla.compile`` span (ending when the listener fires, tagged with its
+  bucket and the jitted function's name), so a ``--trace-out`` timeline
+  shows where compiles sat and what they compiled.
 
 All ``device.*`` metrics are ``persistent=True`` (process-scoped, like
 ``compilecache.*``): compile/recompile history must survive the
@@ -54,16 +50,16 @@ import os
 import threading
 import time
 from contextlib import contextmanager
-from typing import Any, Callable, Dict, Iterator, Optional, Sequence, Tuple
+from typing import Any, Dict, Iterator, Optional, Sequence
 
 from mythril_tpu.observability.metrics import get_registry
+from mythril_tpu.observability.tracer import get_tracer
 
 __all__ = [
     "bucket_tag",
     "current_bucket",
     "device_meta",
     "dispatch_scope",
-    "harvest_analysis",
     "heartbeat_source",
     "install",
     "install_deviceplane",
@@ -99,9 +95,6 @@ _ctx = threading.local()
 # a program we thought was warm.
 _compiled_tags: Dict[str, int] = {}
 _session_seq = [0]
-# program tags whose cost/memory analysis has been harvested (or is
-# in flight) — the AOT lower/compile must run once per executable
-_analyzed_tags: set = set()
 
 
 def bucket_tag(bucket: Sequence[int]) -> str:
@@ -152,6 +145,9 @@ def _on_duration(event: str, duration_secs: float, **kwargs) -> None:
     reg = get_registry()
     tag = current_bucket() or _UNTAGGED
     if event == _EV_BACKEND_COMPILE:
+        get_tracer().record_span(
+            "xla.compile", "device", t0 - duration_secs, duration_secs,
+            args={"bucket": tag, "fun": kwargs.get("fun_name")})
         reg.observe("device.compile_wall_s", duration_secs)
         reg.counter("device.compile_wall_s_total", persistent=True,
                     initial=0.0).inc(duration_secs)
@@ -273,126 +269,12 @@ def observe_pull(seconds: float, tag: Optional[str] = None) -> None:
     _stamp("frontier.pull_device_s", seconds, tag)
 
 
-# -- cost / memory analysis harvest ---------------------------------------
-
-
-def _analysis_unavailable(reason: str) -> None:
-    get_registry().labeled_counter(
-        "device.analysis_unavailable", persistent=True, label_name="reason"
-    ).inc(reason)
-
-
-def _set_bucket_gauge(name: str, tag: str, value: float) -> None:
-    g = get_registry().gauge(name, persistent=True, default={},
-                             label_name="bucket")
-    cur = g.value if isinstance(g.value, dict) else {}
-    nxt = dict(cur)
-    nxt[tag] = value
-    g.set(nxt)
-
-
-def _first_dict(obj: Any) -> Optional[Dict[str, Any]]:
-    """cost_analysis() has returned a dict, a list of per-computation
-    dicts, or None across jax versions — normalize to one dict."""
-    if isinstance(obj, dict):
-        return obj
-    if isinstance(obj, (list, tuple)) and obj and isinstance(obj[0], dict):
-        return obj[0]
-    return None
-
-
-def _harvest_worker(fn, args_thunk: Callable[[], Tuple], tag: str) -> None:
-    reg = get_registry()
-    t0 = time.perf_counter()
-    try:
-        # runs after the live dispatch compiled + persistently cached the
-        # program, so this compile is a cache read, not a second compile;
-        # scope it so any event it emits still attributes to the bucket
-        with dispatch_scope(tag):
-            compiled = fn.lower(*args_thunk()).compile()
-    except Exception:
-        # AOT path itself unavailable (donation mismatch, backend quirk):
-        # degrade, never crash the run that scheduled us
-        _analysis_unavailable("lower_compile:error")
-        return
-    finally:
-        reg.observe("device.analysis_harvest_s", time.perf_counter() - t0)
-
-    try:
-        cost = _first_dict(compiled.cost_analysis())
-    except Exception:
-        cost = None
-        _analysis_unavailable("cost_analysis:error")
-    if cost is None:
-        _analysis_unavailable("cost_analysis:none")
-    else:
-        flops = cost.get("flops")
-        if isinstance(flops, (int, float)) and flops > 0:
-            _set_bucket_gauge("device.flops_per_segment", tag, float(flops))
-        else:
-            _analysis_unavailable("cost_analysis:no_flops")
-        touched = cost.get("bytes accessed")
-        if isinstance(touched, (int, float)) and touched > 0:
-            _set_bucket_gauge("device.bytes_accessed_per_segment", tag,
-                              float(touched))
-
-    try:
-        mem = compiled.memory_analysis()
-    except Exception:
-        mem = None
-        _analysis_unavailable("memory_analysis:error")
-    if mem is None:
-        _analysis_unavailable("memory_analysis:none")
-    else:
-        hbm = 0.0
-        for attr in ("argument_size_in_bytes", "output_size_in_bytes",
-                     "temp_size_in_bytes"):
-            v = getattr(mem, attr, None)
-            if isinstance(v, (int, float)) and v > 0:
-                hbm += float(v)
-        if hbm > 0:
-            _set_bucket_gauge("device.hbm_bytes", tag, hbm)
-        else:
-            # a CPU backend's memory_analysis object reports zeros —
-            # absence must not read as a free program
-            _analysis_unavailable("memory_analysis:empty")
-
-
-def harvest_analysis(fn, args_thunk: Callable[[], Tuple], tag: str) -> bool:
-    """Harvest ``cost_analysis``/``memory_analysis`` once per executable.
-
-    ``fn`` is the jitted segment, ``args_thunk`` builds the example
-    arguments lazily on the worker thread (keeps the dispatch path
-    free).  Runs AFTER the first real dispatch of the program so the
-    AOT re-compile is served by the persistent XLA compilation cache
-    rather than racing the live compile.  Idempotent per ``tag``.
-    """
-    if os.environ.get("MYTHRIL_DEVICE_ANALYSIS", "1") in ("0", "false",
-                                                          "off"):
-        return False
-    with _install_lock:
-        if tag in _analyzed_tags:
-            return False
-        _analyzed_tags.add(tag)
-    threading.Thread(
-        target=_harvest_worker, args=(fn, args_thunk, tag),
-        name="mythril-device-analysis", daemon=True,
-    ).start()
-    return True
-
-
 # -- surfaces --------------------------------------------------------------
 
 
 def _counter_value(reg, name: str) -> float:
     m = reg._metrics.get(name)
     return m.value if m is not None and hasattr(m, "value") else 0
-
-
-def _gauge_dict(reg, name: str) -> Dict[str, Any]:
-    m = reg._metrics.get(name)
-    v = getattr(m, "value", None)
-    return dict(v) if isinstance(v, dict) else {}
 
 
 def _labeled_dict(reg, name: str) -> Dict[str, Any]:
@@ -427,12 +309,6 @@ def device_meta() -> Dict[str, Any]:
     out["compile_wall_s_by_bucket"] = {
         k: round(float(v), 3) for k, v in sorted(by_bucket.items())
     }
-    flops = _gauge_dict(reg, "device.flops_per_segment")
-    if flops:
-        out["flops_per_segment"] = flops
-    hbm = _gauge_dict(reg, "device.hbm_bytes")
-    if hbm:
-        out["hbm_bytes"] = hbm
     seg = reg._metrics.get("frontier.segment_device_s")
     if seg is not None and getattr(seg, "count", 0):
         out["segment_device_s"] = {
@@ -441,9 +317,6 @@ def device_meta() -> Dict[str, Any]:
             "p50": round(seg.percentile(0.5) or 0.0, 6),
             "p95": round(seg.percentile(0.95) or 0.0, 6),
         }
-    unavailable = _labeled_dict(reg, "device.analysis_unavailable")
-    if unavailable:
-        out["analysis_unavailable"] = dict(sorted(unavailable.items()))
     overhead = float(_counter_value(reg, "device.plane_overhead_s"))
     wall = getattr(seg, "sum", 0.0) or 0.0
     out["overhead_pct"] = round(100.0 * overhead / wall, 4) if wall else 0.0
@@ -464,11 +337,10 @@ def heartbeat_source() -> Dict[str, Any]:
 
 
 def reset_for_tests() -> None:
-    """Forget attribution state (compiled shapes, harvested programs).
+    """Forget attribution state (compiled shapes).
 
     Tests only — the listeners stay registered; registry metrics are
     reset separately via ``MetricsRegistry.reset``.
     """
     with _install_lock:
         _compiled_tags.clear()
-        _analyzed_tags.clear()

@@ -440,18 +440,11 @@ class FleetAggregator:
             # metric; summarize the worker's XLA-facing totals for top
             compile_s = ws.counters.get("device.compile_wall_s_total", 0)
             recompiles = ws.counters.get("device.recompiles_total", 0)
-            hbm = ws.gauges.get("device.hbm_bytes")
-            if compile_s or recompiles or hbm:
-                device: Dict[str, Any] = {
+            if compile_s or recompiles:
+                out["device"] = {
                     "compile_s": round(float(compile_s), 3),
                     "recompiles": int(recompiles),
                 }
-                if isinstance(hbm, dict) and hbm:
-                    device["hbm_bytes"] = max(
-                        v for v in hbm.values()
-                        if isinstance(v, (int, float))
-                    )
-                out["device"] = device
             return out
 
     def summary(self) -> Dict[str, Any]:

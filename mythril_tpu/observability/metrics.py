@@ -26,6 +26,18 @@ at segment/query granularity, not per instruction).  Plain ``+=`` on a
 ``LabeledCounter`` item and facade property writes remain main-thread
 constructs.  Registry *registration* is separately lock-protected because
 worker threads may create metrics concurrently.
+
+Accounts
+--------
+Because the analysis-scoped metrics are zeroed at every analysis start,
+the registry also keeps a bounded ring of per-analysis *accounts*: each
+one holds the analysis's start and end on the epoch clock
+(``time.time_ns()``), a snapshot of its analysis-scoped metrics, the
+compile seconds it paid, and one record per issue it reported.  An
+account opens with ``open_account()`` (at the analysis-scope sweep) and
+closes with ``close_account()`` (when the analysis's report is built);
+``accounts(since_ns)`` reads them back, so a reader can total any window
+of analyses without watching the sweeps.
 """
 
 from __future__ import annotations
@@ -34,7 +46,8 @@ import bisect
 import collections
 import re
 import threading
-from typing import Any, Dict, List, Optional, Tuple, Union
+import time
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 __all__ = [
     "Counter",
@@ -287,12 +300,23 @@ class Histogram:
         return out
 
 
+#: closed accounts the registry keeps (a long-lived service must not grow)
+ACCOUNT_RING = 256
+#: persistent counter whose gain over an analysis is its compile seconds
+_COMPILE_TOTAL = "device.compile_wall_s_total"
+
+
 class MetricsRegistry:
     """Name -> metric map with get-or-create accessors and scoped reset."""
 
-    def __init__(self):
+    def __init__(self, account_ring: int = ACCOUNT_RING):
         self._lock = threading.Lock()
         self._metrics: Dict[str, Any] = {}
+        self._accounts: collections.deque = collections.deque(
+            maxlen=account_ring)
+        # the running analysis's account: its start stamp and the compile
+        # total then
+        self._open: Optional[Dict[str, Any]] = None
 
     def _get_or_create(self, name: str, factory, kind) -> Any:
         m = self._metrics.get(name)
@@ -364,6 +388,57 @@ class MetricsRegistry:
             for name, m in items
             if name.startswith(prefix)
         }
+
+    # -- per-analysis accounts -------------------------------------------
+
+    def _compile_total(self) -> float:
+        m = self._metrics.get(_COMPILE_TOTAL)
+        return float(m.value) if isinstance(m, Counter) else 0.0
+
+    def open_account(self) -> None:
+        """Start the running analysis's account (at its scope sweep)."""
+        self._open = {"start_ns": time.time_ns(),
+                      "compile_s": self._compile_total()}
+
+    def close_account(self, issues: Sequence[Dict[str, Any]] = (),
+                      deadline_ns: Optional[int] = None) -> Dict[str, Any]:
+        """Close the running analysis's account and keep it in the ring.
+
+        ``deadline_ns`` is the analysis's execution deadline (epoch ns),
+        if it set one: ``frontier.drain_s`` is the time from its passing to
+        now, 0 when it did not pass, and is set before the snapshot of
+        analysis-scoped metrics so the account and the report agree on
+        it.  ``issues`` are the report's per-issue records.
+        """
+        with self._lock:
+            end_ns = time.time_ns()
+            acct, self._open = self._open, None
+        if acct is None:
+            acct = {"start_ns": end_ns, "compile_s": self._compile_total()}
+        drain_ns = 0
+        if deadline_ns is not None and acct["start_ns"] <= deadline_ns < end_ns:
+            drain_ns = end_ns - deadline_ns
+        self.counter("frontier.drain_s", initial=0.0).set(drain_ns / 1e9)
+        with self._lock:
+            items = sorted(self._metrics.items())
+        out = {
+            "start_ns": acct["start_ns"],
+            "end_ns": end_ns,
+            "metrics": {name: m.snapshot() for name, m in items
+                        if not m.persistent},
+            "compile_s": round(self._compile_total() - acct["compile_s"], 6),
+            "issues": [dict(i) for i in issues],
+        }
+        with self._lock:
+            self._accounts.append(out)
+        return out
+
+    def accounts(self, since_ns: int = 0) -> List[Dict[str, Any]]:
+        """Closed accounts of analyses that started at or after
+        ``since_ns`` (epoch ns), oldest first."""
+        with self._lock:
+            accounts = list(self._accounts)
+        return [a for a in accounts if a["start_ns"] >= since_ns]
 
 
 _registry = MetricsRegistry()

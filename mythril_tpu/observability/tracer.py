@@ -24,6 +24,16 @@ Design constraints, in order:
 Timestamps are microseconds relative to the tracer's origin (first
 construction or last ``reset()``), which is what the trace viewers
 expect — they render relative time, not epoch time.
+
+One clock with the device trace: the tracer keeps an anchor pair
+``(time.time_ns(), time.perf_counter_ns())`` read together, taken at
+construction and ``reset()`` and again at each export (so slew between
+the two clocks stays bounded over a long run).  ``epoch_ns(ts)`` maps a
+span stamp onto the epoch clock, and ``spans_on_clock(origin_epoch_ns)``
+gives every span as ns after an epoch instant — pass a ``jax.profiler``
+trace's ``profile_start_time`` (its ``Task Environment`` plane) and the
+spans land on the device timeline, no host events needed.  The Chrome
+export writes the anchor into ``otherData.clock``.
 """
 
 from __future__ import annotations
@@ -34,7 +44,7 @@ import json
 import threading
 import time
 from collections import deque
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 __all__ = [
     "Tracer",
@@ -52,6 +62,15 @@ _PH_INSTANT = "i"
 _PH_FLOW_START = "s"
 _PH_FLOW_END = "f"
 _PH_COUNTER = "C"
+
+
+def _clock_anchor() -> Tuple[int, int]:
+    """``(time.time_ns(), time.perf_counter_ns())`` read together: the
+    epoch read bracketed by two monotonic reads, paired with their mean."""
+    p0 = time.perf_counter_ns()
+    wall = time.time_ns()
+    p1 = time.perf_counter_ns()
+    return wall, (p0 + p1) // 2
 
 
 class _NullContext:
@@ -123,6 +142,7 @@ class Tracer:
         # (observability/fleet.py) can drain exactly-once
         self.total = 0
         self._origin = time.perf_counter()
+        self._anchor = _clock_anchor()
         # tid -> human name, captured lazily on first record per thread
         # (worker pools name their threads mythril-feas-N etc.), plus
         # synthetic ids for non-thread tracks registered explicitly.
@@ -344,6 +364,26 @@ class Tracer:
             out["foreign_processes"] = processes
         return out
 
+    def epoch_ns(self, ts: float) -> int:
+        """Epoch ns (``time.time_ns()`` clock) of a span stamp ``ts``
+        (seconds after the tracer's origin, as ``spans()`` gives it)."""
+        wall_ns, perf_ns = self._anchor
+        return wall_ns + round((self._origin + ts) * 1e9) - perf_ns
+
+    def spans_on_clock(self, origin_epoch_ns: int) -> List[Dict[str, Any]]:
+        """``spans()`` with ``start_ns`` / ``duration_ns``: ns after the
+        epoch instant ``origin_epoch_ns`` (a profiler trace's
+        ``profile_start_time``), the time base of its device events.
+        An export: the anchor is taken again first."""
+        with self._lock:
+            self._anchor = _clock_anchor()
+        out = []
+        for rec in self.spans():
+            rec["start_ns"] = self.epoch_ns(rec["ts"]) - origin_epoch_ns
+            rec["duration_ns"] = round(rec["dur"] * 1e9)
+            out.append(rec)
+        return out
+
     def thread_names(self) -> Dict[int, str]:
         """Snapshot of tid -> track name seen so far."""
         with self._lock:
@@ -354,6 +394,7 @@ class Tracer:
             self._buf.clear()
             self.dropped = 0
             self._origin = time.perf_counter()
+            self._anchor = _clock_anchor()
             self._thread_names.clear()
             self._foreign.clear()
 
@@ -365,6 +406,7 @@ class Tracer:
 
         pid = os.getpid()
         with self._lock:
+            self._anchor = _clock_anchor()
             raw = list(self._buf)
             names = dict(self._thread_names)
             dropped = self.dropped
@@ -453,6 +495,12 @@ class Tracer:
             "otherData": {
                 "producer": "mythril_tpu.observability",
                 "dropped_spans": dropped,
+                # ts 0 on the epoch clock, and the anchor pair it rests on
+                "clock": {
+                    "origin_epoch_ns": self.epoch_ns(0.0),
+                    "anchor_epoch_ns": self._anchor[0],
+                    "anchor_perf_counter_ns": self._anchor[1],
+                },
             },
         }
 

@@ -842,12 +842,6 @@ class AnalysisService:
                     k: round(float(v), 3)
                     for k, v in sorted(by_bucket.items())
                 }
-            hbm = roll.gauges.get("device.hbm_bytes")
-            if isinstance(hbm, dict) and hbm:
-                out["hbm_bytes"] = dict(hbm)
-            flops = roll.gauges.get("device.flops_per_segment")
-            if isinstance(flops, dict) and flops:
-                out["flops_per_segment"] = dict(flops)
             return out
 
     def fleet_prometheus_text(self) -> str:

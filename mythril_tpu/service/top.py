@@ -89,11 +89,6 @@ def format_top(stats: Dict[str, Any], address: Optional[str] = None) -> str:
         )
         if device.get("shape_churn"):
             line += f"  shape-churn {device['shape_churn']}"
-        hbm = device.get("hbm_bytes") or {}
-        if hbm:
-            line += "  hbm {:.1f}MB".format(
-                max(hbm.values()) / 1e6 if isinstance(hbm, dict) else 0.0
-            )
         lines.append(line)
 
     workers = stats.get("workers") or []

@@ -116,10 +116,19 @@ class SolverStatistics:
     def reset(self) -> None:
         """Zero the solver-scoped metrics (not the ``enabled`` switch)."""
         _metrics_registry().reset(prefix="solver.")
-        # force-create the backing counters so snapshots always carry
-        # the full solver block even before the first query
-        _ = (self.query_count, self.solver_time, self.probe_hits,
-             self.cdcl_calls, self.unknown_as_unsat)
+        self.materialize()
+
+    def materialize(self) -> None:
+        """Force-create the backing counters (tier counters included) so
+        snapshots always carry the full solver block, even before the
+        first query."""
+        reg = _metrics_registry()
+        for name, initial in self._counters.values():
+            reg.counter(name, initial=initial)
+        for busy, calls, decided in _TIER_COUNTERS.values():
+            reg.counter(busy, initial=0.0)
+            reg.counter(calls)
+            reg.counter(decided)
 
     def __repr__(self):
         return (
@@ -128,6 +137,38 @@ class SolverStatistics:
             f"cdcl calls: {self.cdcl_calls}, "
             f"unknown treated as unsat: {self.unknown_as_unsat}"
         )
+
+
+#: the solving tiers whose crossings are accounted: the cross-run query
+#: cache, the known-bits pre-filter, the host candidate probe, the tape-VM
+#: device probe, the device SAT search and the native CDCL
+TIERS = ("querycache", "prefilter", "host_probe", "device_probe",
+         "devsolver", "cdcl")
+_TIER_COUNTERS = {
+    t: (f"solver.tier.{t}.busy_s", f"solver.tier.{t}.calls",
+        f"solver.tier.{t}.decided")
+    for t in TIERS
+}
+
+
+def tier_done(tier: str, t0: float, decided: int = 0) -> None:
+    """Account one call of ``tier`` that began at ``t0`` (``perf_counter``)
+    and decided ``decided`` queries (SAT with a checked model, or UNSAT):
+    its host wall, device waits included, into ``solver.tier.<tier>.*``,
+    and under tracing a ``smt.<tier>`` span (the query cache has its own
+    ``querycache.hit`` span).  A batch call counts once."""
+    dur = time.perf_counter() - t0
+    busy, calls, decided_name = _TIER_COUNTERS[tier]
+    reg = _metrics_registry()
+    reg.counter(busy, initial=0.0).inc(dur)
+    reg.counter(calls).inc()
+    if decided:
+        reg.counter(decided_name).inc(decided)
+    tracer = _otrace.get_tracer()
+    if tracer.enabled and tier != "querycache":
+        tracer.record_span(
+            "smt." + tier, "device" if tier == "device_probe" else "smt",
+            t0, dur, args={"decided": decided})
 
 
 # ---------------------------------------------------------------------------
@@ -1215,7 +1256,10 @@ def _fast_path(
             # model probing inside the cache mirrors the replay tier below;
             # batched callers (replay=False) replay over a merged union
             # themselves, so they take only the exact/core tiers here
+            t_tier = time.perf_counter()
             cached = qc.lookup(conj, budget_ms=budget_ms, probe_models=replay)
+            tier_done("querycache", t_tier,
+                       int(cached is not None and cached[0] != UNKNOWN))
             if cached is not None:
                 if cached[0] != UNKNOWN:
                     _model_cache.remember(key, cached[0], cached[1])
@@ -1332,7 +1376,9 @@ def check_satisfiable_batch(
     if pending and getattr(global_args, "prefilter", True):
         from mythril_tpu.absdomain import prefilter_batch
 
+        t_tier = time.perf_counter()
         killed = prefilter_batch([conj for _i, conj, _k in pending])
+        tier_done("prefilter", t_tier, sum(1 for dead in killed if dead))
         still = []
         for (i, conj, key), dead in zip(pending, killed):
             if dead:
@@ -1351,7 +1397,10 @@ def check_satisfiable_batch(
     if pending and getattr(global_args, "devsolver", True):
         from mythril_tpu import devsolver
 
+        t_tier = time.perf_counter()
         verdicts = devsolver.decide_batch([conj for _i, conj, _k in pending])
+        tier_done("devsolver", t_tier,
+                   sum(1 for dstat, _asg in verdicts if dstat in ("sat", "unsat")))
         still = []
         for (i, conj, key), (dstat, asg) in zip(pending, verdicts):
             if dstat == "unsat":
@@ -1411,37 +1460,44 @@ def _batch_probe_device(pending, results, config) -> None:
             note_host_route("probe_warming")
             return  # host fallback until the interpreter is compiled
 
-    # union of conjuncts in deterministic first-seen order
-    all_conjs: List[Term] = []
-    col_of: Dict[int, int] = {}
-    for _i, conj, _key in pending:
-        for c in conj:
-            if c.tid not in col_of:
-                col_of[c.tid] = len(all_conjs)
-                all_conjs.append(c)
-    compiled = tape_vm.compile_tape(all_conjs)
+    t_tier = time.perf_counter()
+    decided = 0
+    try:
+        # union of conjuncts in deterministic first-seen order
+        all_conjs: List[Term] = []
+        col_of: Dict[int, int] = {}
+        for _i, conj, _key in pending:
+            for c in conj:
+                if c.tid not in col_of:
+                    col_of[c.tid] = len(all_conjs)
+                    all_conjs.append(c)
+        compiled = tape_vm.compile_tape(all_conjs)
 
-    per_set = max(8, (config.max_rounds * config.candidates_per_round) // max(1, len(pending)))
-    candidates: List[Assignment] = []
-    for _i, conj, _key in pending:
-        candidates.extend(CandidateGenerator(conj, config).generate(per_set))
-    _metrics_registry().counter("smt.device_probe_dispatches").inc()
-    truth = compiled.evaluate_batch(candidates)  # [B, C_total]
+        per_set = max(8, (config.max_rounds * config.candidates_per_round)
+                      // max(1, len(pending)))
+        candidates: List[Assignment] = []
+        for _i, conj, _key in pending:
+            candidates.extend(CandidateGenerator(conj, config).generate(per_set))
+        _metrics_registry().counter("smt.device_probe_dispatches").inc()
+        truth = compiled.evaluate_batch(candidates)  # [B, C_total]
 
-    for i, conj, key in pending:
-        cols = [col_of[c.tid] for c in conj]
-        rows = truth[:, cols].all(axis=1)
-        for b in rows.nonzero()[0]:
-            asg = candidates[int(b)]
-            try:
-                vals = evaluate(conj, asg)
-            except Exception:
-                continue
-            if all(vals[c] for c in conj):
-                SolverStatistics().inc("probe_hits")
-                _model_cache.remember(key, SAT, asg)
-                results[i] = True
-                break
+        for i, conj, key in pending:
+            cols = [col_of[c.tid] for c in conj]
+            rows = truth[:, cols].all(axis=1)
+            for b in rows.nonzero()[0]:
+                asg = candidates[int(b)]
+                try:
+                    vals = evaluate(conj, asg)
+                except Exception:
+                    continue
+                if all(vals[c] for c in conj):
+                    SolverStatistics().inc("probe_hits")
+                    _model_cache.remember(key, SAT, asg)
+                    results[i] = True
+                    decided += 1
+                    break
+    finally:
+        tier_done("device_probe", t_tier, decided)
 
 
 # how many recent models the cheap tiers replay per query (each miss costs
@@ -1615,6 +1671,7 @@ def _solve_conjunction_impl(
     # cost on wide frontiers (profiled: ~8ms+2.6ms per query x thousands)
     if config.sat_biased and getattr(global_args, "probe_backend", "auto") != "cdcl":
         # (forced-exact mode skips every heuristic tier, this one included)
+        t_tier = time.perf_counter()
         gen = CandidateGenerator(conjuncts, config)
         for asg in gen.generate(8, deadline=t0 + config.timeout_ms / 2000.0):
             vals = evaluate(conjuncts, asg)
@@ -1623,7 +1680,9 @@ def _solve_conjunction_impl(
                 if use_cache:
                     _model_cache.remember(cache_key, SAT, asg)
                 stats.inc("solver_time", time.perf_counter() - t0)
+                tier_done("host_probe", t_tier, 1)
                 return SAT, asg
+        tier_done("host_probe", t_tier)
 
     # tier 0.58: abstract pre-filter (interval + known-bits over the packed
     # tape) — same bottom-by-abstraction soundness as the tiers below but
@@ -1632,7 +1691,10 @@ def _solve_conjunction_impl(
     if getattr(global_args, "prefilter", True):
         from mythril_tpu.absdomain import refute as _abs_refute
 
-        if _abs_refute(conjuncts):
+        t_tier = time.perf_counter()
+        refuted = _abs_refute(conjuncts)
+        tier_done("prefilter", t_tier, int(refuted))
+        if refuted:
             if use_cache:
                 _model_cache.remember(cache_key, UNSAT, None)
             stats.inc("solver_time", time.perf_counter() - t0)
@@ -1658,7 +1720,9 @@ def _solve_conjunction_impl(
     if getattr(global_args, "devsolver", True):
         from mythril_tpu import devsolver
 
+        t_tier = time.perf_counter()
         dstat, dasg = devsolver.decide(conjuncts)
+        tier_done("devsolver", t_tier, int(dstat in ("sat", "unsat")))
         if dstat == "unsat":
             if use_cache:
                 _model_cache.remember(cache_key, UNSAT, None)
@@ -1734,11 +1798,11 @@ def _solve_conjunction_impl(
 
             if bitblast.available():
                 stats.inc("cdcl_calls")
-                with _otrace.span("smt.cdcl", cat="smt", forced=True):
-                    status, asg = bitblast.solve(
-                        conjuncts,
-                        max(1.0, t0 + config.timeout_ms / 1000.0 - time.perf_counter()),
-                    )
+                t_tier = time.perf_counter()
+                status, asg = bitblast.solve(
+                    conjuncts,
+                    max(1.0, t0 + config.timeout_ms / 1000.0 - time.perf_counter()),
+                )
                 if status == SAT and asg is not None:
                     vals = evaluate(conjuncts, asg)
                     if all(vals[c] for c in conjuncts):
@@ -1747,11 +1811,16 @@ def _solve_conjunction_impl(
                 elif status == UNSAT:
                     _model_cache.remember(cache_key, UNSAT, None)
                     result = (UNSAT, None)
+                tier_done("cdcl", t_tier, int(result[0] != UNKNOWN))
         except ImportError:
             pass
         stats.inc("solver_time", time.perf_counter() - t0)
         return result
 
+    # the probe tier: host candidates, or one tape-VM dispatch of them
+    # (device_probe), each followed by the host's local repair
+    t_probe = time.perf_counter()
+    probe_tier = "host_probe"
     if gen is None:
         gen = CandidateGenerator(conjuncts, config)
     scalar_vars = gen.scalar_vars
@@ -1796,6 +1865,7 @@ def _solve_conjunction_impl(
         else None
     )
     if compiled is not None:
+        probe_tier = "device_probe"
         # the batched dispatch needs the whole pool upfront
         with _otrace.span("smt.candidates", cat="smt", n=total):
             candidates.extend(gen.generate(total, deadline))
@@ -1806,9 +1876,7 @@ def _solve_conjunction_impl(
         # validation of the winner (exactness belt-and-braces).
         import numpy as _np
 
-        with _otrace.span(
-            "smt.device_probe", cat="device", batch=len(candidates)
-        ), _otrace.device_annotation("smt.device_probe"):
+        with _otrace.device_annotation("smt.device_probe"):
             truth = _evaluate_candidates_device(compiled, candidates)  # [B, C]
         scores = truth.sum(axis=1)
         for b in _np.argsort(-scores, kind="stable"):
@@ -1818,6 +1886,7 @@ def _solve_conjunction_impl(
                 stats.inc("probe_hits")
                 stats.inc("solver_time", time.perf_counter() - t0)
                 _model_cache.remember(cache_key, SAT, candidates[b])
+                tier_done(probe_tier, t_probe, 1)
                 return SAT, candidates[b]
             if time.perf_counter() > deadline:
                 break
@@ -1847,6 +1916,7 @@ def _solve_conjunction_impl(
                 stats.inc("probe_hits")
                 stats.inc("solver_time", time.perf_counter() - t0)
                 _model_cache.remember(cache_key, SAT, asg)
+                tier_done(probe_tier, t_probe, 1)
                 return SAT, asg
             if score > best_score:
                 best_score, best_asg = score, asg
@@ -1882,9 +1952,11 @@ def _solve_conjunction_impl(
                 stats.inc("probe_hits")
                 stats.inc("solver_time", time.perf_counter() - t0)
                 _model_cache.remember(cache_key, SAT, asg)
+                tier_done(probe_tier, t_probe, 1)
                 return SAT, asg
             if score >= best_score:
                 best_score, best_asg = score, asg
+    tier_done(probe_tier, t_probe)
 
     # tier 2: exact bit-blasting CDCL if the native library is available
     try:
@@ -1901,12 +1973,14 @@ def _solve_conjunction_impl(
                 # a nonpositive timeout.  Other host-only queries keep strict
                 # wall-clock discipline (mutation pruner's 500ms etc.).
                 budget = max(1.0, budget)
-            with _otrace.span("smt.cdcl", cat="smt", conjuncts=len(conjuncts)):
-                status, asg = bitblast.solve(conjuncts, budget)
+            t_tier = time.perf_counter()
+            status, asg = bitblast.solve(conjuncts, budget)
             stats.inc("solver_time", time.perf_counter() - t0)
             if status == SAT and asg is not None and check_asg(asg):
+                tier_done("cdcl", t_tier, 1)
                 _model_cache.remember(cache_key, SAT, asg)
                 return SAT, asg
+            tier_done("cdcl", t_tier, int(status == UNSAT))
             if status == UNSAT:
                 # exact verdict: safe to memoize (UNKNOWN never is — a larger
                 # budget on a later identical query may still find a model)

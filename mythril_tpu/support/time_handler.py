@@ -21,6 +21,12 @@ class TimeHandler(metaclass=Singleton):
         self._start_time = time.time()
         self._execution_time = execution_time_seconds
 
+    def deadline(self) -> Optional[float]:
+        """Epoch seconds at which the execution time runs out, if started."""
+        if self._start_time is None:
+            return None
+        return self._start_time + self._execution_time
+
     def time_remaining(self) -> float:
         """Seconds left; very large if never started."""
         if self._start_time is None:

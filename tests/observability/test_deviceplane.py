@@ -47,6 +47,29 @@ def test_compile_event_attributed_to_dispatching_bucket():
         by_bucket.get("4x8x2x1", 0) + 0.25)
 
 
+def test_compile_event_is_a_span_under_tracing():
+    """With the tracer on, a backend compile is an ``xla.compile`` span
+    that ends when the listener fires, tagged with bucket and function."""
+    from mythril_tpu.observability import get_tracer
+
+    tracer = get_tracer()
+    tracer.reset()
+    tracer.enabled = True
+    try:
+        before = time.time_ns()
+        with dp.dispatch_scope((3, 3, 3, 3)):
+            dp._on_duration(dp._EV_BACKEND_COMPILE, 0.5, fun_name="segment")
+        after = time.time_ns()
+        (span,) = [s for s in tracer.spans() if s["name"] == "xla.compile"]
+        end = tracer.epoch_ns(span["ts"] + span["dur"])
+    finally:
+        tracer.enabled = False
+        tracer.reset()
+    assert span["dur"] == 0.5
+    assert span["args"] == {"bucket": "3x3x3x3", "fun": "segment"}
+    assert before - 1e6 <= end <= after + 1e6
+
+
 def test_recompile_counted_per_session_not_per_event():
     """One dispatch emits a BURST of backend-compile events (the segment
     plus jax's auxiliary executables); a recompile is a burst for a known
@@ -127,32 +150,6 @@ def test_observe_segment_emits_labeled_series():
         "5x5x5x5", 0) >= 1.0
     text = prometheus_text()
     assert 'frontier_segment_device_s_sum{bucket="5x5x5x5"}' in text
-
-
-def test_analysis_degrades_to_unavailable_counter():
-    """A backend where the AOT path raises must degrade to a labeled
-    reason counter — never a crash, never a zero gauge."""
-
-    class _Boom:
-        def lower(self, *args):
-            raise RuntimeError("no AOT here")
-
-    assert dp.harvest_analysis(_Boom(), tuple, "6x6x6x6") is True
-    deadline = time.time() + 5.0
-    while time.time() < deadline:
-        if _labeled("device.analysis_unavailable").get(
-                "lower_compile:error", 0):
-            break
-        time.sleep(0.02)
-    assert _labeled("device.analysis_unavailable").get(
-        "lower_compile:error", 0) >= 1
-    # idempotent per tag: the second request is a no-op
-    assert dp.harvest_analysis(_Boom(), tuple, "6x6x6x6") is False
-
-
-def test_harvest_analysis_env_gate(monkeypatch):
-    monkeypatch.setenv("MYTHRIL_DEVICE_ANALYSIS", "0")
-    assert dp.harvest_analysis(object(), tuple, "gated") is False
 
 
 def test_install_env_gate(monkeypatch):

@@ -339,3 +339,73 @@ def test_writer_storm_with_concurrent_readers():
             r.join()
     assert not reader_errors
     assert t.summary()["spans"] + t.dropped == N_STORM_THREADS * N_STORM_ITER * 2
+
+
+# -- one clock with the device trace ----------------------------------------
+
+
+def _profile_start_time(trace_dir):
+    """``profile_start_time`` (epoch ns) from the ``Task Environment``
+    plane of the newest ``.xplane.pb`` under ``trace_dir``, and the
+    events of its other planes by name."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                            recursive=True), key=os.path.getmtime)[-1]
+    start, events = None, {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "Task Environment":
+            start = dict(plane.stats)["profile_start_time"]
+        for line in plane.lines:
+            for ev in line.events:
+                events.setdefault(ev.name, []).append(ev.start_ns)
+    return start, events
+
+
+def test_spans_on_clock_line_up_with_the_profiler(tracer, tmp_path):
+    """A span entered with a ``TraceAnnotation`` lands, mapped onto the
+    profiler's ``profile_start_time``, within 1 ms of the annotation."""
+    import time
+
+    jax = pytest.importorskip("jax")
+
+    opts = jax.profiler.ProfileOptions()
+    opts.host_tracer_level = 1
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        time.sleep(0.05)
+        with tracer.span("clock.probe", cat="test"), \
+                jax.profiler.TraceAnnotation("clock.probe.annotation"):
+            time.sleep(0.01)
+    finally:
+        jax.profiler.stop_trace()
+    start, events = _profile_start_time(str(tmp_path))
+    assert start is not None
+    (annotated,) = events["clock.probe.annotation"]
+    (span,) = [s for s in tracer.spans_on_clock(start) if s["name"] == "clock.probe"]
+    assert abs(span["start_ns"] - annotated) < 1e6
+    assert span["duration_ns"] >= 10e6
+
+
+@pytest.mark.parametrize("export", ["spans_on_clock", "chrome_trace"])
+def test_exports_map_ts_zero_onto_the_epoch_clock(tracer, export):
+    """Both exports place the tracer's origin on the epoch clock; the
+    anchor is taken again at each, and stays within 1 ms of the truth."""
+    import time
+
+    with tracer.span("epoch.probe", cat="test"):
+        pass
+    before = time.time_ns()
+    if export == "chrome_trace":
+        clock = tracer.chrome_trace()["otherData"]["clock"]
+        origin = clock["origin_epoch_ns"]
+        assert clock["anchor_epoch_ns"] >= before
+    else:
+        (span,) = tracer.spans_on_clock(0)
+        origin = span["start_ns"] - round(span["ts"] * 1e9)
+    assert origin == pytest.approx(tracer.epoch_ns(0.0), abs=1e6)
+    assert origin <= before
