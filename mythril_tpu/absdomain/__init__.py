@@ -22,7 +22,10 @@ Verdicts are memoized under the same canonical frozenset-of-tids key the
 feasibility pool dedups on, so the pipeline gate and the solver gate never
 evaluate the same query twice.  ``prefilter.{evaluated,killed,fallthrough}``
 counters and the ``prefilter.eval_s`` histogram account every fresh
-evaluation; memo hits are free and uncounted.
+evaluation; memo hits are free and uncounted.  A batch whose union tape
+would pass ``tape.MAX_NODES`` is evaluated in groups of rows whose unions
+fit; ``prefilter.packs`` counts the tapes evaluated and
+``prefilter.grouped_rows`` the rows that went through groups.
 """
 
 from __future__ import annotations
@@ -32,7 +35,7 @@ import time
 from collections import OrderedDict
 from typing import List, Optional, Sequence
 
-from mythril_tpu.native.bitblast import Unsupported
+from mythril_tpu.native.bitblast import UnionSize, Unsupported
 from mythril_tpu.smt.terms import Term
 from mythril_tpu.support.device import DeviceFailure
 
@@ -46,10 +49,14 @@ _memo: "OrderedDict[frozenset, bool]" = OrderedDict()
 _memo_lock = threading.Lock()
 
 
-def _counters():
+def _registry():
     from mythril_tpu.observability import get_registry
 
-    reg = get_registry()
+    return get_registry()
+
+
+def _counters():
+    reg = _registry()
     return (
         reg.counter("prefilter.evaluated"),
         reg.counter("prefilter.killed"),
@@ -76,8 +83,54 @@ def _memo_put(key: frozenset, verdict: bool) -> None:
             _memo.popitem(last=False)
 
 
+def _fits(size: UnionSize, row: Sequence[Term], max_nodes: int) -> bool:
+    try:
+        return size.add(row) <= max_nodes
+    except Unsupported:
+        return False
+
+
+def _row_groups(rows: List[Sequence[Term]]) -> List[List[int]]:
+    """Split ``rows`` into groups whose union tapes fit the node budget.
+
+    A batch whose whole union fits is one group, as it always was.  Else
+    the rows are taken in the order of their conjuncts, so that rows that
+    share a path prefix sit side by side, and a group closes before the
+    row that would push its union past ``tape.MAX_NODES`` and at the
+    largest row bucket, so each group is one known-bits dispatch.  A row
+    over the budget, or with structure the tape cannot hold, ends up alone.
+    """
+    from mythril_tpu.absdomain import device, tape
+
+    whole = UnionSize()
+    if all(_fits(whole, row, tape.MAX_NODES) for row in rows):
+        return [list(range(len(rows)))]
+    order = sorted(range(len(rows)), key=lambda i: [t.tid for t in rows[i]])
+    groups: List[List[int]] = [[]]
+    size = UnionSize()
+    for i in order:
+        full = len(groups[-1]) == device.ROW_BUCKETS[-1]
+        if (full or not _fits(size, rows[i], tape.MAX_NODES)) and groups[-1]:
+            groups.append([])
+            size = UnionSize()
+            _fits(size, rows[i], tape.MAX_NODES)
+        groups[-1].append(i)
+    return groups
+
+
 def _evaluate_rows(rows: List[Sequence[Term]]) -> List[Optional[bool]]:
     """Pack + evaluate; ``None`` marks fallthrough (unsupported structure)."""
+    groups = _row_groups(rows)
+    if len(groups) > 1:
+        _registry().counter("prefilter.grouped_rows").inc(len(rows))
+    out: List[Optional[bool]] = [None] * len(rows)
+    for group in groups:
+        for i, v in zip(group, _evaluate_group([rows[i] for i in group])):
+            out[i] = v
+    return out
+
+
+def _evaluate_group(rows: List[Sequence[Term]]) -> List[Optional[bool]]:
     from mythril_tpu.absdomain import domains, tape
 
     try:
@@ -85,12 +138,12 @@ def _evaluate_rows(rows: List[Sequence[Term]]) -> List[Optional[bool]]:
     except Unsupported:
         if len(rows) == 1:
             return [None]
-        # one poisoned row must not cost its siblings the pass
-        out: List[Optional[bool]] = []
-        for row in rows:
-            out.extend(_evaluate_rows([row]))
-        return out
+        # one poisoned row must not cost its siblings the pass: halve
+        # the group until the poisoned row falls through alone
+        mid = len(rows) // 2
+        return _evaluate_group(rows[:mid]) + _evaluate_group(rows[mid:])
 
+    _registry().counter("prefilter.packs").inc()
     km, kv, kb_ref = _eval_kb(pack)
     lo, hi, iv_ref = domains.eval_iv_host(pack)
     v = domains.verdicts(pack, lo, hi, km, kv, iv_ref | kb_ref)

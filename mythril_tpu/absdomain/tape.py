@@ -1,11 +1,16 @@
 """Pack a batch of constraint rows onto one shared abstract-evaluation tape.
 
 The pre-filter reuses ``native/bitblast.py``'s serialization wholesale: the
-UNION of every row's conjuncts is serialized once (interned terms make
-sibling rows share their entire path prefix, so the union tape is barely
-larger than the widest single row), and each row keeps only the list of tape
-nodes it actually asserts.  Evaluation then runs one pass over the tape with
-a row axis — the whole frontier batch at once.
+UNION of the rows' conjuncts is serialized once (interned terms let rows
+share the subterms they have in common), and each row keeps only the list of
+tape nodes it actually asserts.  Evaluation then runs one pass over the tape
+with a row axis.
+
+A union can be far larger than any one row.  A second transaction's prune
+batch on the benchmark's ``flags`` contract has 896 rows of 822-826 nodes
+each and a 36,646-node union, over ``MAX_NODES``.  ``prefilter_batch``
+therefore sizes the union first (``bitblast.UnionSize``) and packs such a
+batch as a few groups of rows whose unions fit: 14 tapes of 64 rows there.
 
 Every abstraction the serializer applies (mux-chain ``select`` rewrite,
 fresh variables for base-array selects / keccak / apply, dropped select

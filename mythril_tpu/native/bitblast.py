@@ -389,6 +389,53 @@ def serialize(
     return tape
 
 
+def _congruence_nodes(sites: int, arity: int) -> int:
+    """Nodes ``_add_congruence`` emits over ``sites`` sites of ``arity`` args."""
+    return sites * (sites - 1) // 2 * (2 * arity + 2)
+
+
+class UnionSize:
+    """Running node count of ``serialize(union, lazy_selects=True)`` as rows
+    of conjuncts join the union one at a time.  Each ``add`` serializes only
+    the terms no earlier row reached, so sizing a union costs one pass over
+    it, and a caller can stop as soon as the count passes its budget."""
+
+    def __init__(self):
+        self._tape = _Tape()
+        self._walked: set = set()
+
+    def add(self, conjuncts: Sequence[Term]) -> int:
+        """Add one row; the union's node count after it.  Raises
+        ``Unsupported`` when the row carries structure the tape cannot
+        express, and so does every later ``add``: no such union serializes."""
+        tape, walked = self._tape, self._walked
+        if tape is None:
+            raise Unsupported("union holds an unsupported row")
+        # topo_order's post-order walk, pruned at terms already serialized
+        stack = [(c, False) for c in conjuncts]
+        try:
+            while stack:
+                t, ready = stack.pop()
+                if ready:
+                    node = _serialize_node(tape, t)
+                    if node is not None:
+                        tape.node_of[t.tid] = node
+                    continue
+                if t.tid in walked:
+                    continue
+                walked.add(t.tid)
+                stack.append((t, True))
+                stack.extend((a, False) for a in t.args if a.tid not in walked)
+        except Unsupported:
+            self._tape = None
+            raise
+        return (
+            len(tape.records)
+            + _congruence_nodes(len(tape.keccaks), 1)
+            + sum(_congruence_nodes(len(s), key[1]) for key, s in tape.applies.items())
+        )
+
+
 # ---------------------------------------------------------------------------
 # Model reconstruction
 # ---------------------------------------------------------------------------
