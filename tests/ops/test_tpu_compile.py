@@ -123,10 +123,11 @@ def test_absdomain_known_bits(one_chip, nodes, rows):
     from mythril_tpu.smt import terms
 
     x = terms.var("tpu_compile_kb", 256)
-    small = device.chunk_args(tape.pack([[terms.ult(x, terms.const(3, 256))]]),
-                              [0])
+    *small, n_nodes = device.chunk_args(
+        tape.pack([[terms.ult(x, terms.const(3, 256))]]), [0])
     nb, rb = small[0].shape[0], small[-1].shape[1]
-    # re-bucket every node (leading) and row (second) axis
+    # re-bucket every node (leading) and row (second) axis; the trip count
+    # stays a 0-d int32
     specs = [
         _spec(one_chip,
               (nodes if a.shape[0] == nb else a.shape[0],)
@@ -134,7 +135,7 @@ def test_absdomain_known_bits(one_chip, nodes, rows):
               + a.shape[2:],
               a.dtype)
         for a in small
-    ]
+    ] + [_spec(one_chip, (), n_nodes.dtype)]
     compiled = device._get_jitted().lower(*specs).compile()
     assert _device_bytes(compiled) < HBM_BYTES
 
