@@ -1,6 +1,12 @@
-"""Hand-assembled benchmark contracts: the BECToken batchTransfer shape.
+"""Test and smoke contracts, and the helpers that analyze them.
 
-The image carries no solc and the reference mount ships no compiled
+The first part holds the small hand-assembled fixtures the tests, the chip
+smoke and the graft entry share (killbilly, the wide fork contract, the
+suicide and gated kill shapes) together with the analysis and recall
+helpers they drive them through.  The rest assembles the real-shaped
+contracts: BECToken's batchTransfer, EtherStore and Rubixi.
+
+BECToken batchTransfer: the image carries no solc and the reference mount ships no compiled
 BECToken, so the wide "real-shaped" workload is assembled here instruction
 by instruction, mirroring the structures solc 0.4 emits for
 ``/root/reference/solidity_examples/BECToken.sol``:
@@ -24,9 +30,150 @@ the symbolic ``cnt``, and multi-tx analysis crosses all of it.
 
 from __future__ import annotations
 
+import time
+from pathlib import Path
 from typing import Dict, List, Tuple
 
 from mythril_tpu.support.support_utils import keccak256
+
+# ---------------------------------------------------------------------------
+# killbilly: a storage-gated selfdestruct that needs a 2-tx chain
+# ---------------------------------------------------------------------------
+
+# activate() selector 0x0a11ce00 -> 0x1e, kill() selector 0x41c0e1b5 -> 0x25
+DISPATCH = (
+    "6000" "35" "60e0" "1c" "80"
+    "630a11ce00" "14" "601e" "57"
+    "6341c0e1b5" "14" "6025" "57"
+    "60006000fd"
+)
+ACTIVATE = "5b600160005500"  # 0x1e: JUMPDEST; SSTORE(0, 1); STOP
+KILL = "5b" "600054" "6001" "14" "6034" "57" "60006000fd" "5b" "33" "ff"
+KILLBILLY = DISPATCH + ACTIVATE + KILL
+_L = f"{len(KILLBILLY) // 2:02x}"
+KILLBILLY_CREATION = f"60{_L}600c60003960{_L}6000f3" + KILLBILLY
+
+# kill() selector -> CALLER; SELFDESTRUCT, anything else reverts
+SUICIDE = bytes.fromhex("60003560e01c6341c0e1b51460145760006000fd5b33ff")
+# x = calldataload(0); require(x < 10); x == 5 -> selfdestruct (feasible),
+# x == 20 -> selfdestruct (infeasible: the branch condition contradicts the
+# range pin, the contradiction the abstract pre-filter refutes)
+GATED = bytes.fromhex(
+    "60003580600a9010600c57005b80600514601c5780601414601c57005b33ff"
+)
+
+
+def wide_contract(n_branches: int) -> bytes:
+    """n independent symbolic branches that immediately reconverge (2^n
+    surviving paths) followed by an unprotected SELFDESTRUCT — the
+    frontier-width shape the batched device interpreter is built for."""
+    out = b""
+    for k in range(n_branches):
+        # PUSH1 k; CALLDATALOAD; PUSH1 1; AND; PUSH2 dest; JUMPI; JUMPDEST
+        dest = len(out) + 10
+        out += bytes([0x60, k, 0x35, 0x60, 0x01, 0x16,
+                      0x61, (dest >> 8) & 0xFF, dest & 0xFF, 0x57, 0x5B])
+    return out + bytes([0x33, 0xFF])  # CALLER; SELFDESTRUCT
+
+
+def killbilly_contract():
+    """killbilly with its creation code, as an analysis target."""
+    from mythril_tpu.frontend.evmcontract import EVMContract
+
+    return EVMContract(
+        code=KILLBILLY, creation_code=KILLBILLY_CREATION, name="KillBilly"
+    )
+
+
+def corpus_dir() -> Path:
+    """Directory of compiled corpus contracts (``*.sol.o`` runtime hex).
+    The corpus is not vendored: callers skip what is absent."""
+    return Path(__file__).parent / "tests" / "integration" / "inputs"
+
+
+def read_runtime(path: Path) -> bytes:
+    return bytes.fromhex(path.read_text().strip().replace("0x", ""))
+
+
+# ---------------------------------------------------------------------------
+# analysis and recall helpers
+# ---------------------------------------------------------------------------
+
+
+def clear_caches() -> None:
+    """Make the next analysis cold: detector issue lists and memos, the
+    model caches and the in-process query cache (a configured disk store
+    stays attached)."""
+    from mythril_tpu.analysis.module.loader import ModuleLoader
+    from mythril_tpu.analysis.security import reset_callback_modules
+    from mythril_tpu.querycache import reset_query_cache
+    from mythril_tpu.smt.solver import clear_model_cache
+    from mythril_tpu.support.model import _get_model_cached
+
+    reset_callback_modules()
+    clear_model_cache()
+    _get_model_cached.cache_clear()
+    reset_query_cache()
+    for module in ModuleLoader().get_detection_modules():
+        module.cache.clear()
+
+
+def analyze(contract, address, tx_count, modules=None, strategy="bfs",
+            timeout=60):
+    """One full analysis: symbolic execution, then the detection modules."""
+    from mythril_tpu.analysis.security import fire_lasers
+    from mythril_tpu.analysis.symbolic import SymExecWrapper
+
+    sym = SymExecWrapper(
+        contract,
+        address=address,
+        strategy=strategy,
+        transaction_count=tx_count,
+        execution_timeout=timeout,
+        modules=modules,
+    )
+    issues = fire_lasers(sym, white_list=modules)
+    return sym, issues
+
+
+def _selects(input_hex: str, selector: int) -> bool:
+    """Does this calldata dispatch to ``selector``?  EVM CALLDATALOAD
+    zero-pads past calldatasize, so exact minimization may shave trailing
+    zero bytes off the selector itself (0x0a11ce00 -> 3-byte calldata)."""
+    data = bytes.fromhex(input_hex[2:] if input_hex.startswith("0x") else input_hex)
+    padded = (data + b"\x00" * 4)[:4]
+    return int.from_bytes(padded, "big") == selector
+
+
+def check_recall(issues) -> None:
+    """killbilly recall: SWC-106 with activate() then kill()."""
+    assert issues, "exploit not found: zero issues"
+    issue = issues[0]
+    assert issue.swc_id == "106", f"wrong SWC id {issue.swc_id}"
+    steps = issue.transaction_sequence["steps"]
+    inputs = [s["input"] for s in steps]
+    assert any(_selects(i, 0x0A11CE00) for i in inputs), "missing activate() tx"
+    assert _selects(inputs[-1], 0x41C0E1B5), "final tx is not kill()"
+
+
+def run_analysis(probe_backend: str):
+    """The killbilly analysis (3 transactions, AccidentallyKillable) under
+    one probe backend, from cold caches.  Returns (sym, issues, wall_s)."""
+    from mythril_tpu.support.support_args import args as global_args
+
+    global_args.probe_backend = probe_backend
+    clear_caches()
+    t0 = time.time()
+    sym, issues = analyze(
+        killbilly_contract(), 0x0901D12E, 3, modules=["AccidentallyKillable"],
+        timeout=300,
+    )
+    return sym, issues, time.time() - t0
+
+
+# ---------------------------------------------------------------------------
+# BECToken batchTransfer
+# ---------------------------------------------------------------------------
 
 
 def selector(signature: str) -> int:

@@ -236,7 +236,7 @@ def phase_bectoken(opts, tmp, on_tpu):
 
 def wide_device_run(opts, extra=()):
     """Phase 3's analysis: the 1024-path wide contract, device-resident."""
-    from bench import _wide_contract
+    from bench_contracts import wide_contract
 
     from mythril_tpu.absdomain import device as kb_device
     from mythril_tpu.devsolver import device as ds_device
@@ -247,7 +247,7 @@ def wide_device_run(opts, extra=()):
     # not fire inside this analysis
     kb_device.warmup()
     ds_device.warmup()
-    code = contract_hex(_wide_contract(opts.wide_branches))
+    code = contract_hex(wide_contract(opts.wide_branches))
     report, fstats, _ = analyze([
         "-c", code, "--bin-runtime", "-t", "1", "-m", "AccidentallyKillable",
         "--frontier", "--frontier-force", "--frontier-width", str(opts.width),

@@ -18,7 +18,7 @@ def repo_compile_cache_dir() -> str:
 
 def arm_compile_cache() -> None:
     """Persist compiled XLA programs on disk across processes; called once
-    by each entry point (CLI, service, ``bench.py``, ``chip_smoke.py``),
+    by each entry point (CLI, service, ``chip_smoke.py``),
     never at import.
 
     The tape VM, the Pallas keccak kernel and the frontier's
@@ -31,7 +31,7 @@ def arm_compile_cache() -> None:
 
     Cache hits/misses are mirrored into the ``compilecache.hits`` /
     ``compilecache.misses`` counters via ``jax.monitoring`` so
-    ``--metrics-out`` snapshots and bench rows show whether a warm start
+    ``--metrics-out`` snapshots and report meta show whether a warm start
     skipped the recompile.
     """
     global _compile_cache_armed

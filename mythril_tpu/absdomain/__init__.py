@@ -8,8 +8,8 @@ bottom (some asserted root must-false, or an empty abstract element) has
 NO concrete model: the original conjunction is UNSAT and the path dies
 without any host round-trip or bit-blasting.  Everything else falls
 through to the existing tiers completely unchanged, so recall is
-untouched by construction and ``bench.py --prefilter-compare`` asserts
-bit-identical issue sets with the filter on and off.
+untouched by construction, and ``tests/absdomain/test_integration.py``
+asserts identical issue sets with the filter on and off.
 
 Entry points
 ------------
@@ -66,7 +66,7 @@ def _counters():
 
 
 def reset_state() -> None:
-    """Drop the verdict memo (tests and bench compare modes)."""
+    """Drop the verdict memo (the parity tests)."""
     with _memo_lock:
         _memo.clear()
 

@@ -17,7 +17,7 @@ This package closes the loop:
 
 ``--no-adaptive`` disables every actuation site; the steering is a
 strict scheduling optimization, so the issue set is bit-identical either
-way (bench ``--adaptive-compare`` asserts it).
+way (``tests/adaptive/test_parity.py`` asserts it).
 """
 
 from mythril_tpu.adaptive.controller import (

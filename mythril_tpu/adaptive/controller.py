@@ -19,7 +19,7 @@ the process-wide mutable half the actuation sites need:
 Everything degrades to a no-op when ``--no-adaptive`` is set: callers
 gate on :attr:`AdaptiveController.enabled`, and the scheduler's FIFO
 fallback is exactly the pre-adaptive injection order (the on/off parity
-contract the bench ``--adaptive-compare`` mode asserts).
+contract ``tests/adaptive/test_parity.py`` asserts).
 """
 
 from __future__ import annotations
@@ -289,7 +289,7 @@ class AdaptiveController:
             self._stop = None
 
     def meta(self) -> Dict[str, Any]:
-        """The ``meta.adaptive`` block for jsonv2 reports and bench."""
+        """The ``meta.adaptive`` block for jsonv2 reports."""
         out = {
             "enabled": self.enabled,
             "plans": int(self._c("plans").value),

@@ -20,8 +20,8 @@ a three-valued verdict per query:
   falls through to the exact tiers unchanged.
 
 Soundness is therefore by construction: the tier can answer or abstain,
-never misdecide.  ``bench.py --devsolver-compare`` asserts bit-identical
-issue sets with the tier on and off.
+never misdecide.  ``tests/devsolver/test_pipeline_parity.py`` asserts
+identical issue sets with the tier on and off.
 
 Entry points: ``decide_batch(rows)`` / ``decide(conjuncts)`` — both
 never raise; ``configure()`` applies analyzer args; ``reset_state()``
@@ -64,7 +64,7 @@ def configure(bit_budget: Optional[int] = None,
 
 
 def reset_state() -> None:
-    """Drop the verdict memo + admission accounting (tests, bench)."""
+    """Drop the verdict memo + admission accounting (tests)."""
     from mythril_tpu.devsolver import admission
 
     with _memo_lock:

@@ -384,7 +384,7 @@ def reset_isolation_gauges() -> None:
     within one analysis (a multi-class dispatch must survive later
     single-class tail rounds), so each fresh analysis zeroes them here
     before its first dispatch — otherwise a long-lived process (daemon,
-    bench harness) would report the previous corpus's split."""
+    test process) would report the previous corpus's split."""
     reg = _get_metrics()
     for name in (
         "frontier.bucket_classes",
@@ -1273,11 +1273,11 @@ class FrontierEngine:
 
         # TTFE fix: a floored bucket shares one compiled program across a
         # cooperative corpus, but a COLD program then pays the big bucket's
-        # XLA compile before the first event can harvest (ttfe_s regression
-        # in BENCH_r05).  Run the OPENING dispatch at the program's natural
-        # bucket — a small program that compiles fast — harvest it, and only
-        # then enter the floored-bucket loop.  Time-to-first-event now rides
-        # the small compile; the big compile amortizes over the rest.
+        # XLA compile before the first event can harvest.  Run the OPENING
+        # dispatch at the program's natural bucket — a small program that
+        # compiles fast — harvest it, and only then enter the floored-bucket
+        # loop.  Time-to-first-event now rides the small compile; the big
+        # compile amortizes over the rest.
         if mesh is None and bucket != natural_bucket and not program_warm:
             nat_cc, _nat_ic, _nat_ac, nat_lc = natural_bucket
             stats = FrontierStatistics()
@@ -1444,22 +1444,13 @@ class FrontierEngine:
             # stay short so the first terminals harvest — and their exploits
             # confirm — quickly; later segments run long to amortize the
             # link round trip.  Keyed on the ANALYSIS-wide segment counter
-            # (reset per contract by the facade/bench), not a per-drain
+            # (reset per contract by the facade), not a per-drain
             # counter: periodic nested drains must not re-pay truncated
             # segments long after the first exploit confirmed.
             cfg = cfg._replace(
                 k_limit=np.int32(min(caps.K, 96 << min(stats.segments, 4)))
             )
             st_dev = push_sharded(st) if mesh is not None else push_state(st)
-            micro = (
-                args.frontier_microbench
-                and not stats.microbench
-                and mesh is None
-            )
-            if micro:
-                micro_args = (
-                    st_dev, dev_arena, arena_len, visited, code_dev, cfg
-                )
             _fid = (_otrace.get_tracer().new_flow_id()
                     if _otrace.get_tracer().enabled else None)
             with _otrace.span(
@@ -1492,14 +1483,6 @@ class FrontierEngine:
             stats.device_instructions += n_exec_host
             stats.segments += 1
             seg_only = time.perf_counter() - t_seg
-            if micro and n_exec_host > 0:
-                t_mb = time.perf_counter()
-                self._run_microbench(segment, micro_args, n_exec_host, st)
-                # the microbench re-dispatches the segment 1+reps times by
-                # design; that wall time is measurement overhead, not
-                # exploration — compensate the execution deadline so a
-                # microbenched run keeps the budget it was configured with
-                deadline += time.perf_counter() - t_mb
             stats.segment_s += seg_only
             _get_metrics().observe("frontier.segment_wall_s", seg_only)
             _devplane.observe_segment(seg_only, _devplane.bucket_tag(bucket))
@@ -1718,48 +1701,6 @@ class FrontierEngine:
         ingestion, the laser-affinity replay pool (args.harvest_workers;
         0 = serial), and the deterministic slot-order commit."""
         HarvestExecutor(self).harvest(st, records, walker, ev_seen, pipe)
-
-    @staticmethod
-    def _run_microbench(segment, micro_args, n_exec: int, st, reps: int = 4) -> None:
-        """Pure device-compute time of one segment, link-independent.
-
-        Wall timers around one dispatch see compute plus the dispatch round
-        trip (anchor measured at a 100 ms dispatch round trip).
-        Chained-dispatch subtraction cancels the round trip: one
-        dispatch plus a forced host readback measures compute+RTT; ``reps``
-        back-to-back dispatches on the SAME inputs (in-order device stream,
-        no donation) measure reps*compute+RTT; the difference divided by
-        reps-1 is the per-segment device compute alone.  Runs once per
-        process on the first productive segment when args.frontier_microbench
-        is set (bench.py's device_microbench block)."""
-        t0 = time.perf_counter()
-        out = segment(*micro_args)
-        np.asarray(out[3])  # n_exec scalar readback forces a true sync
-        t_one = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        outs = [segment(*micro_args) for _ in range(reps)]
-        np.asarray(outs[-1][3])
-        t_many = time.perf_counter() - t0
-        compute = max((t_many - t_one) / max(reps - 1, 1), 1e-9)
-        # packed host->device push excludes events (rebuilt empty on
-        # device); the packed pull rides the same layout + 2 scalars
-        push_bytes = 4 * sum(
-            int(np.prod(f.shape))
-            for name, f in zip(st._fields, st)
-            if name != "events"
-        )
-        FrontierStatistics().microbench = {
-            "segment_compute_s": round(compute, 4),
-            "instructions_per_s": round(n_exec / compute, 1),
-            "n_exec_per_segment": int(n_exec),
-            "dispatch_plus_link_s": round(t_one, 4),
-            "bytes_pushed_per_segment": push_bytes,
-            # the packed pull rides the same layout + the arena_len /
-            # n_exec / max_live scalars (step.pull_harvest)
-            "bytes_pulled_meta_per_segment": push_bytes + 12,
-            "width": int(st.halt.shape[0]),
-            "reps": reps,
-        }
 
     def _lineage_constraint_rows(self, rec) -> List[int]:
         """Arena rows of the branch conditions appended along this path

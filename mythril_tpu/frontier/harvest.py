@@ -2,9 +2,8 @@
 
 The harvest — drain device events into path records, replay finished paths
 through the walker, recycle slots — is the host side of every segment and
-the measured critical path once the pipeline keeps the device busy
-(harvest_share_pct at 43-69% of wall on wide workloads, BENCH_r05).  This
-module replaces engine._harvest's three hot pieces:
+the critical path once the pipeline keeps the device busy.  This module
+replaces engine._harvest's three hot pieces:
 
 1. **Vectorized event ingestion** (``ingest_events``).  The per-slot
    ``for slot / for k`` Python loops become one NumPy batch decode over the
@@ -208,7 +207,7 @@ def _replay_subgroups(walker, subgroups: List[List[PathRecord]],
 
 # The replay pool is process-wide and persistent (spawning threads per
 # harvest would cost more than short replays take); it is resized lazily
-# when --harvest-workers changes between analyses (bench compare modes)
+# when --harvest-workers changes between analyses (the parity tests)
 _pool: Optional[ThreadPoolExecutor] = None
 _pool_size = 0
 

@@ -42,12 +42,11 @@ Correction protocol (the part that makes chaining sound):
     occupied device slots with no host record — the orphan scan clears
     them and schedules the clear as a correction.
 
-Sync points (the only places the pipeline intentionally drains): the first
-microbenched dispatch, host arena appends for spill re-injection (an
-in-flight segment appends device rows at the same indices), reclaiming
-device-owned free slots for a backed-up seed queue, and the final drain —
-an in-flight segment is always pulled and harvested before the loop exits,
-never discarded.
+Sync points (the only places the pipeline intentionally drains): host
+arena appends for spill re-injection (an in-flight segment appends device
+rows at the same indices), reclaiming device-owned free slots for a
+backed-up seed queue, and the final drain — an in-flight segment is always
+pulled and harvested before the loop exits, never discarded.
 """
 
 from __future__ import annotations
@@ -770,10 +769,9 @@ class PipelinedRunner:
             # every free slot is exposed to the device again
             for slot in range(self.caps.B):
                 self.ledger.device_owned[slot] = self.records[slot] is None
-            full_args = (st_dev, self.dev_arena, self.arena_len,
-                         self.visited, self.code_dev, cfg)
-            out = self.segment(*full_args)
-        return out, full_args
+            out = self.segment(st_dev, self.dev_arena, self.arena_len,
+                               self.visited, self.code_dev, cfg)
+        return out
 
     def _chain(self, inflight, arena_override=None):
         from mythril_tpu.frontier.step import chain_dispatch
@@ -809,17 +807,13 @@ class PipelinedRunner:
         narrow_harvests = 0
         run_segments = 0
         stop: Optional[str] = None
-        # microbench timings are single-device figures; skip it on a mesh
-        # (the synchronous loop applies the same gate)
-        micro_pending = bool(args.frontier_microbench and not stats.microbench
-                             and self.mesh is None)
 
         hb = get_heartbeat()
         hb.register("pipeline", self._heartbeat_sample)
         hb_started = False
         if not hb.running:
             # CLI runs with --heartbeat-out arm the sampler up front; any
-            # other pipelined run (facade embedding, bench) starts it here
+            # other pipelined run (facade embedding, tests) starts it here
             # so pool/ledger depth gauges are sampled, not set-on-mutation
             hb.start(period_s=getattr(args, "heartbeat_interval", 0.5),
                      out_path=getattr(args, "heartbeat_out", None))
@@ -834,7 +828,7 @@ class PipelinedRunner:
         _dscope.__enter__()
 
         t0 = time.perf_counter()
-        inflight, full_args = self._dispatch_full()
+        inflight = self._dispatch_full()
         inflight_sid = self._last_dispatch_sid
         dispatch_wall = time.perf_counter() - t0
         prev_st = self.st
@@ -856,7 +850,7 @@ class PipelinedRunner:
                      ).any()
                 )
                 want_sync = bool(
-                    micro_pending or self.reinject_q
+                    self.reinject_q
                     or (self.seed_queue and free_owned)
                     or (self.adaptive_parked and free_owned)
                 )
@@ -928,14 +922,6 @@ class PipelinedRunner:
                 if nxt is not None:
                     reg.counter("pipeline.overlap_segments").inc()
                 _eng._WARM_PROGRAMS.add(self.program_key)
-
-                if micro_pending and n_exec_host > 0:
-                    t_mb = time.perf_counter()
-                    eng._run_microbench(
-                        self.segment, full_args, n_exec_host, new_st
-                    )
-                    self.deadline += time.perf_counter() - t_mb
-                    micro_pending = False
 
                 # ---- harvest (overlaps the in-flight nxt segment)
                 carried = self.ledger.carry_forward(new_st, prev_st)
@@ -1085,7 +1071,7 @@ class PipelinedRunner:
                     self._reinject()
                 self.refill()
                 t0 = time.perf_counter()
-                inflight, full_args = self._dispatch_full()
+                inflight = self._dispatch_full()
                 inflight_sid = self._last_dispatch_sid
                 dispatch_wall = time.perf_counter() - t0
                 self.arena.freeze()
@@ -1115,18 +1101,6 @@ class PipelinedRunner:
                 reg.gauge("pipeline.overlap_ratio").set(
                     round(overlap / total_har, 4)
                 )
-            # the microbench ran at a sync point (full pull), so its
-            # bytes_pulled estimate is the full-state figure; overwrite it
-            # with the measured steady-state delta-pull average
-            mb = stats.microbench
-            pulls = reg.counter("pipeline.delta_pulls").value
-            if mb and pulls:
-                mb = dict(mb)
-                mb["bytes_pulled_meta_per_segment"] = int(
-                    reg.counter("pipeline.delta_pull_bytes").value / pulls
-                )
-                mb["delta_pull_segments"] = int(pulls)
-                stats.microbench = mb
 
         if stop == "slow-bail":
             self.slow_bailed = True

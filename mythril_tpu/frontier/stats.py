@@ -72,17 +72,6 @@ class FrontierStatistics(metaclass=Singleton):
         """timeout/arena/narrow/batch-full"""
         return get_registry().labeled_counter(_PREFIX + "parks_by_reason")
 
-    @property
-    def microbench(self) -> dict:
-        # device-only efficiency numbers (engine._run_microbench): pure
-        # segment compute time via chained re-dispatch subtraction, so the
-        # per-chip story is measurable independent of the host<->device link
-        return get_registry().gauge(_PREFIX + "microbench", default={}).value
-
-    @microbench.setter
-    def microbench(self, v: dict) -> None:
-        get_registry().gauge(_PREFIX + "microbench", default={}).set(v)
-
     def _materialize(self) -> None:
         """Force-create the backing metrics so snapshots always carry the
         full frontier block even before the first increment."""
@@ -104,7 +93,6 @@ class FrontierStatistics(metaclass=Singleton):
         reg.histogram(_PREFIX + "bucket_compile_s")
         reg.labeled_counter(_PREFIX + "parks_by_opcode")
         reg.labeled_counter(_PREFIX + "parks_by_reason")
-        reg.gauge(_PREFIX + "microbench", default={})
 
     def reset(self) -> None:
         """Zero the frontier-scoped metrics.
@@ -140,5 +128,4 @@ class FrontierStatistics(metaclass=Singleton):
             # in the registry snapshot / meta.frontier instead
             "parks_by_opcode": dict(self.parks_by_opcode.most_common()),
             "parks_by_reason": dict(self.parks_by_reason.most_common()),
-            **({"microbench": self.microbench} if self.microbench else {}),
         }

@@ -1201,17 +1201,15 @@ def build_segment(caps: Caps):
         return (t < k) & running.any() & room
 
     # NO-INPUT-DONATION INVARIANT: this jit must never donate its inputs.
-    # engine._run_microbench re-dispatches the compiled segment on the SAME
-    # device buffers (micro_args are captured before the timed call and
-    # reused 1+reps times), and the engine re-pushes state across nested
-    # drains the same way; donate_argnums would let XLA alias those buffers
-    # into the outputs and the second dispatch would read garbage.  Kept as
-    # an explicit empty tuple + assert so a future "optimization" trips
-    # loudly instead of corrupting microbench numbers silently.
+    # The engine re-pushes state across nested drains and re-dispatches on
+    # the same device buffers; donate_argnums would let XLA alias those
+    # buffers into the outputs and the second dispatch would read garbage.
+    # Kept as an explicit empty tuple + assert so a future "optimization"
+    # trips loudly instead of corrupting path state silently.
     _SEGMENT_DONATE_ARGNUMS: tuple = ()
     assert _SEGMENT_DONATE_ARGNUMS == (), (
-        "frontier segment must not donate inputs: _run_microbench and the "
-        "engine's re-dispatch paths reuse the pushed device buffers"
+        "frontier segment must not donate inputs: the engine's re-dispatch "
+        "paths reuse the pushed device buffers"
     )
 
     @partial(jax.jit, donate_argnums=_SEGMENT_DONATE_ARGNUMS)

@@ -168,8 +168,7 @@ def _add_analysis_options(parser) -> None:
         default=True,
         help="disable the large-code frontier (per-code bucket isolation "
         "and packed-code paging) and pad every code to one corpus-wide "
-        "size bucket; the issue set is identical either way (bench.py "
-        "--paging-compare gates exactly this toggle)",
+        "size bucket; the issue set is identical either way",
     )
     group.add_argument(
         "--code-page-budget",
@@ -293,8 +292,7 @@ def _add_analysis_options(parser) -> None:
         help="keep only the base (intra-procedural) static passes: no "
         "value-set jump refinement, function recovery, reachable-edge "
         "oracle or cross-contract call graph; the issue set is identical "
-        "either way (bench.py --staticpass-compare gates exactly this "
-        "toggle)",
+        "either way",
     )
     group.add_argument(
         "--staticpass-report",

@@ -106,7 +106,7 @@ def _materialize_facades() -> None:
 
 
 def observability_meta() -> dict:
-    """Snapshot block embedded in report meta and BENCH rows."""
+    """Snapshot block embedded in report meta."""
     _materialize_facades()
     tracer = get_tracer()
     meta = {"metrics": get_registry().snapshot()}

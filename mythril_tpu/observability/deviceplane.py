@@ -4,7 +4,7 @@ Every telemetry plane built so far (tracer, flight recorder, fleet,
 watchtower, history) observes the *host* — the device frontier was a
 black box: XLA compile wall, per-bucket segment cost, HBM footprint and
 recompile churn were invisible, so a perf drift diagnosis meant a human
-eyeballing two BENCH_*.json files.  This module turns the device side
+eyeballing two result files.  This module turns the device side
 into ordinary registry metrics:
 
 * ``install()`` registers ``jax.monitoring`` listeners.  JAX emits
@@ -32,7 +32,7 @@ into ordinary registry metrics:
 
 All ``device.*`` metrics are ``persistent=True`` (process-scoped, like
 ``compilecache.*``): compile/recompile history must survive the
-per-analysis registry sweep, and consumers (bench, fleet deltas) read
+per-analysis registry sweep, and consumers (the benchmark, fleet deltas) read
 them as before/after deltas.  Because they are ordinary registry
 metrics, the PR-13 fleet fabric ships them per worker with no extra
 wiring — pooled runs get ``fleet_device_*{worker=…}`` series for free.

@@ -16,7 +16,7 @@ one process-wide ledger:
   stamped with exactly ONE of :data:`TERM_CLASSES`.  ``stamp`` increments
   the per-class labeled counter and the total counter together, so the
   partition invariant (sum over classes == total terminated) holds by
-  construction and is asserted in tests, bench rows, and the CI smoke.
+  construction and is asserted in tests and the CI smoke.
 
 * **Solver hotspots** — feasibility-solve wall time attributed to the
   program point (codehash-tagged pc) whose query burned the budget, as a
@@ -388,7 +388,7 @@ class ExplorationLedger:
     # -- snapshots ------------------------------------------------------
 
     def meta(self) -> Dict[str, Any]:
-        """The ``meta.exploration`` block for jsonv2 reports and bench."""
+        """The ``meta.exploration`` block for jsonv2 reports."""
         terminated = self.terminated()
         total = self.terminated_total()
         return {

@@ -132,7 +132,7 @@ class Gauge:
 
 
 def _copy_default(default: Any) -> Any:
-    # mutable defaults (microbench dict) must not be shared across resets
+    # mutable defaults (dicts, lists) must not be shared across resets
     return default.copy() if isinstance(default, (dict, list)) else default
 
 

@@ -2,8 +2,8 @@
 
 The four measurement planes (flight deck, request telemetry, fleet
 fabric, exploration ledger) record everything and watch nothing — a
-regression is only caught when a human runs the bench gate or stares at
-``myth top``.  The watchtower closes that loop:
+regression is only caught when a human stares at ``myth top``.  The
+watchtower closes that loop:
 
 * **Objectives** are declarative: a named target over one metric —
   a histogram quantile (``ttfe_p95``), a counter ratio (``error_rate``),

@@ -8,7 +8,7 @@ Process-wide singleton, mirroring the other telemetry/cache subsystems::
     hit = get_query_cache().lookup(conjuncts, budget_ms=2000)
 
 The solver hooks (smt/solver.py) call ``lookup``/``record``; everything
-else — bench's warm-vs-cold mode, the facade's flag propagation, tests —
+else — the facade's flag propagation, the warm-vs-cold tests —
 goes through the module-level helpers below.
 """
 

@@ -90,7 +90,7 @@ def _registry():
 
 def materialize_counters() -> None:
     """Force-create the querycache.* counters so registry snapshots (report
-    meta, --metrics-out, bench) always carry the full block, zeroes
+    meta, --metrics-out) always carry the full block, zeroes
     included, even for runs where the cache never fired."""
     reg = _registry()
     for name in _COUNTERS:
@@ -149,7 +149,7 @@ class QueryCache:
                 self._add_core(cid, hashes, write=False)
 
     def reset(self) -> None:
-        """Drop the in-process layers (bench cold runs / per-test isolation).
+        """Drop the in-process layers (cold runs / per-test isolation).
         The disk store survives and its cores are re-indexed, so a
         configured warm run hits only through disk."""
         with self._lock:

@@ -273,7 +273,7 @@ class AnalysisService:
         self._profile_waits: Dict[int, Dict[str, Any]] = {}
         self._profile_lock = threading.Lock()
         self.watchtower = None  # armed in start() when config.watchtower
-        # fault hook (bench serve-load, CI breach drill): stall every
+        # fault hook (CI breach drill): stall every
         # submission ahead of admission by this many seconds, so the
         # injected latency lands inside the TTFE/queue-wait budgets
         self._inject_submit_sleep = float(

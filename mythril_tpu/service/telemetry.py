@@ -37,8 +37,8 @@ One ``RequestTelemetry`` instance rides each ``AnalysisService``:
   phases, issue digests) appended to the daemon's ``--request-log``.
 
 Everything here runs at request granularity — nothing touches the
-per-instruction hot path — and ``bench.py --serve-load`` asserts issue
-digests stay bit-identical to solo runs with all of it enabled.
+per-instruction hot path — and ``tests/service/test_serve_integration.py``
+asserts issue digests stay bit-identical to solo runs with it enabled.
 """
 
 from __future__ import annotations

@@ -34,8 +34,9 @@ On top of the base passes sits the INTERPROCEDURAL layer:
 
 Everything is OVER-approximate: a may_reach miss or a reachable
 instruction marked dead is impossible by construction, so issue sets are
-identical with and without the pass (asserted in tests and by
-``bench.py --staticpass-compare``).  Consumers:
+identical with and without the pass (asserted by
+``tests/staticpass/test_parity.py`` and ``test_interproc_parity.py``).
+Consumers:
 
 * ``analysis/module/loader.py`` skips statically irrelevant detectors,
 * ``analysis/symbolic.py`` never registers their hooks (hooks elided),
@@ -46,8 +47,8 @@ identical with and without the pass (asserted in tests and by
   the corrected coverage denominator (``coverage_pct_reachable``),
 * ``--staticpass-report`` / `myth static` / ``meta.staticpass`` dump the
   CFG/taint/function/call-graph summary as JSON, and the ``staticpass.*``
-  counters flow through the observability registry into report meta,
-  ``--metrics-out`` and bench JSON.
+  counters flow through the observability registry into report meta
+  and ``--metrics-out``.
 
 ``--no-staticpass`` (args.staticpass = False) disables all of it;
 ``--no-staticpass-interproc`` keeps the base passes but disables the

@@ -74,7 +74,7 @@ def _get_model_cached(
     session_enable: Sequence[int] = (),
 ) -> Tuple[Model, bool]:
     # (kept as a separate function so the memo layer above stays readable;
-    # ``cache_clear`` mirrors the old lru_cache surface for bench/tests)
+    # ``cache_clear`` mirrors the old lru_cache surface for tests)
     opt = Optimize(
         ProbeConfig(
             max_rounds=args.probe_rounds,
@@ -97,8 +97,8 @@ def _get_model_cached(
     return opt.model(), opt.proven_optimal
 
 
-# compatibility with the old lru_cache surface (bench/_clear_caches and the
-# recall-differential suite call _get_model_cached.cache_clear())
+# compatibility with the old lru_cache surface (bench_contracts.clear_caches
+# and the recall-differential suite call _get_model_cached.cache_clear())
 _get_model_cached.cache_clear = _model_memo.clear
 
 _dump_counter = [0]

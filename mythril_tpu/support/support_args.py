@@ -65,10 +65,6 @@ class Args:
     # with the pipelined runner (chained dispatches run as one SPMD
     # program); --no-mesh is the single-device escape hatch
     frontier_mesh: bool = True
-    # measure pure device-compute time of the first segment (chained
-    # re-dispatch subtraction, round-trip-independent) into
-    # FrontierStatistics().microbench — bench.py's device_microbench block
-    frontier_microbench: bool = False
     # persistent SMT query cache (mythril_tpu/querycache): the in-process
     # LRU + reuse tiers run whenever query_cache is True; setting a dir
     # adds the disk-backed cross-run/cross-shard store
@@ -86,8 +82,8 @@ class Args:
     staticpass: bool = True
     # interprocedural layer on top of the base pass (value-set jump
     # refinement, function recovery, reachable-edge oracle, call graph);
-    # --no-staticpass-interproc keeps the base passes only — the bench
-    # parity gate compares exactly this toggle
+    # --no-staticpass-interproc keeps the base passes only
+    # (tests/staticpass/test_interproc_parity.py compares this toggle)
     staticpass_interproc: bool = True
     # large-code frontier (mythril_tpu/frontier/code): per-code bucket
     # isolation (codes cluster into size classes, each dispatched with its
@@ -95,7 +91,7 @@ class Args:
     # packed-code paging (codes beyond the residency budget keep only a
     # hot window resident; cold jumps fault to the host for a repack).
     # Issue-set-identical either way; --no-code-paging is the escape
-    # hatch (and the parity baseline for bench.py --paging-compare)
+    # hatch (and the parity baseline in tests/frontier/test_code_buckets.py)
     code_paging: bool = True
     # instruction-axis residency budget for packed-code paging: codes
     # whose instruction count exceeds the grown bucket of this value page
@@ -116,7 +112,7 @@ class Args:
     # decision procedure between the pre-filter and the exact tiers.
     # UNSAT is exact, SAT models are concrete_eval-validated before trust,
     # UNKNOWN falls through; --no-devsolver is the escape hatch (and the
-    # parity baseline for bench.py --devsolver-compare)
+    # parity baseline in tests/devsolver/test_pipeline_parity.py)
     devsolver: bool = True
     # admission: maximum free decision bits after known-bits/interval
     # narrowing for a query to enter the device tier
@@ -140,8 +136,8 @@ class Args:
     # uncovered reachable edges, resurrects budget-parked paths when
     # slots free, and targets concolic flips.  A scheduling-only
     # optimization — the issue set is bit-identical either way;
-    # --no-adaptive is the escape hatch (and the parity baseline for
-    # bench.py --adaptive-compare)
+    # --no-adaptive is the escape hatch (and the parity baseline in
+    # tests/adaptive/test_parity.py)
     adaptive: bool = True
     # terminate exploration once reachable-edge/instruction coverage
     # reaches this percent (or all explored codes plateau below it):

@@ -47,8 +47,7 @@ def check(ok: bool, what: str) -> None:
 
 
 def run_analysis(out_dir: pathlib.Path) -> dict:
-    from bench import KILLBILLY, KILLBILLY_CREATION, _clear_caches
-    from mythril_tpu.frontend.evmcontract import EVMContract
+    from bench_contracts import analyze, clear_caches, killbilly_contract
     from mythril_tpu.frontier import engine as _eng
     from mythril_tpu.observability import get_registry, get_tracer
     from mythril_tpu.observability.flightrecorder import (
@@ -75,15 +74,12 @@ def run_analysis(out_dir: pathlib.Path) -> dict:
     args.frontier_mesh = True
     _eng._SLOW_CODES.clear()
     _eng._NARROW_CODES.clear()
-    _clear_caches()
-
-    from bench import _analyze
+    clear_caches()
 
     try:
-        _, issues = _analyze(
-            EVMContract(code=KILLBILLY, creation_code=KILLBILLY_CREATION,
-                        name="KillBilly"),
-            0x0901D12E, 3, modules=["AccidentallyKillable"], timeout=300,
+        _, issues = analyze(
+            killbilly_contract(), 0x0901D12E, 3,
+            modules=["AccidentallyKillable"], timeout=300,
         )
         check(any(i.swc_id == "106" for i in issues),
               "recall: the killbilly selfdestruct was found")

@@ -122,3 +122,32 @@ def test_batch_check_prefilter_gate(monkeypatch):
     out = solver.check_satisfiable_batch(rows)
     assert out == [True, False]
     assert get_registry().counter("prefilter.killed").value >= 1
+
+
+# ---------------------------------------------------------------------------
+# end to end: --no-prefilter parity on the exploit shapes
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("name", ["suicide", "gated", "killbilly"])
+def test_prefilter_keeps_issue_set_and_gated_branch_dies(name, monkeypatch):
+    """Pipelined frontier with the pre-filter on and off: identical issue
+    sets, the off run evaluates nothing, and on ``gated`` the filter
+    refutes the infeasible branch (x == 20 under x < 10) itself."""
+    from tests.parity import exploit, parity_run
+
+    target, txs = exploit(name)
+    # killbilly runs every detection module: its feasibility traffic is
+    # module confirmation demands, the fallthrough side of the filter
+    modules = None if name == "killbilly" else ["AccidentallyKillable"]
+    runs = {
+        on: parity_run(monkeypatch, target, txs, modules, ("prefilter.",),
+                       prefilter=on, pipeline=True, frontier_mesh=False)
+        for on in (True, False)
+    }
+    (on, on_snap), (off, off_snap) = runs[True], runs[False]
+    assert any(swc == "106" for swc, _ in on), f"recall lost: {on}"
+    assert on == off
+    assert off_snap.get("prefilter.evaluated", 0) == 0, off_snap
+    if name == "gated":
+        assert on_snap["prefilter.killed"] > 0, on_snap

@@ -2,8 +2,8 @@
 
 Each test gets its own :class:`AdaptiveController` with an injected
 :class:`MetricsRegistry`, so nothing here touches the process-wide
-singleton or the global registry — the tier-1 invariant the bench
-``--adaptive-compare`` mode asserts end-to-end."""
+singleton or the global registry; ``test_parity.py`` asserts the on/off
+contract end to end."""
 
 from typing import Optional
 

@@ -4,7 +4,8 @@ The controller only reorders and retimes frontier compute; it must never
 change WHAT is explored to completion.  The fast tests pin the engine's
 FIFO fallback gates (tier-1); the ``slow``-marked e2e runs the real
 cooperative device frontier twice and asserts bit-identical issue sets,
-mirroring ``bench.py --adaptive-compare``.
+and the parametrised multi-code batches (exploit, wide and loop-tail mixes)
+add steering engagement and the coverage stop's segment saving.
 """
 
 import pytest
@@ -143,3 +144,54 @@ def test_coverage_target_latches_stop_without_losing_issues():
     # kill instructions sit in the denominator), so the early stop must
     # not cost recall on this workload
     assert issues == base_issues
+
+
+def _mix(name):
+    from bench_contracts import KILLBILLY, wide_contract
+
+    suicide, gated = bytes.fromhex(SUICIDE_HEX), bytes.fromhex(GATED_HEX)
+    # (jobs, transactions, coverage target of the steered run): steering
+    # only departs from FIFO when the seed queue holds distinct codes, so
+    # every workload is a multi-code batch
+    return {
+        "exploit_mix": ([("suicide", suicide), ("gated", gated),
+                         ("killbilly", bytes.fromhex(KILLBILLY))], 2, None),
+        "wide_mix": ([(f"wide{n}", wide_contract(n)) for n in (3, 4, 5, 6)],
+                     1, None),
+        "loop_tail": ([("loop_tail", bytes.fromhex(LOOP_TAIL_HEX)),
+                       ("suicide", suicide)], 1, 90.0),
+    }[name]
+
+
+@pytest.mark.parametrize("name", ["exploit_mix", "wide_mix", "loop_tail"])
+def test_steering_keeps_issue_set_on_multi_code_batches(name, monkeypatch):
+    """--no-adaptive parity on multi-code batches: identical issue sets
+    with SWC-106 found, the off run neither plans nor resteers, the mixes
+    really resteer, and on ``loop_tail`` the --coverage-target stop
+    dispatches fewer segments than the unrolled unsteered run."""
+    from tests.parity import cooperative_parity_run
+
+    jobs, txs, target = _mix(name)
+
+    def run(steered):
+        out = cooperative_parity_run(
+            monkeypatch, jobs, txs, ("adaptive.",), adaptive=steered,
+            coverage_target=target if steered else None, pipeline=True,
+            frontier_mesh=False,
+            # above loop_tail's 511 iterations: the unsteered run unrolls
+            # to its natural exit
+            loop_bound=600,
+        )
+        return (*out, get_adaptive_controller().stop_state())
+
+    off, off_snap, off_segments, _ = run(False)
+    on, on_snap, on_segments, stop = run(True)
+    assert "106" in {swc for _, swc, _, _ in on}, on
+    assert on == off
+    assert not off_snap.get("adaptive.resteered_slots", 0), off_snap
+    assert not off_snap.get("adaptive.plans", 0), off_snap
+    if target is None:
+        assert on_snap["adaptive.resteered_slots"] > 0, on_snap
+    else:
+        assert stop is not None and stop["coverage_target_met"], stop
+        assert on_segments < off_segments, (on_segments, off_segments)

@@ -84,7 +84,7 @@ def test_resume_continues_analysis(tmp_path):
     from mythril_tpu.frontend.evmcontract import EVMContract
     from mythril_tpu.analysis.module.loader import ModuleLoader
 
-    import bench  # killbilly bytecode fixtures live in the benchmark
+    from bench_contracts import KILLBILLY, KILLBILLY_CREATION
 
     for module in ModuleLoader().get_detection_modules():
         module.cache.clear()
@@ -92,7 +92,7 @@ def test_resume_continues_analysis(tmp_path):
 
     ckpt = str(tmp_path / "frontier.json")
     contract = EVMContract(
-        code=bench.KILLBILLY, creation_code=bench.KILLBILLY_CREATION, name="KB"
+        code=KILLBILLY, creation_code=KILLBILLY_CREATION, name="KB"
     )
     # phase 1: run only the first transaction, checkpointing the frontier
     sym = SymExecWrapper(

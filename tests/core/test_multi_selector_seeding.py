@@ -8,7 +8,7 @@ must find exactly the same issues either way.
 
 import pytest
 
-from bench import KILLBILLY, KILLBILLY_CREATION
+from bench_contracts import KILLBILLY, KILLBILLY_CREATION
 from mythril_tpu.analysis.security import fire_lasers, reset_callback_modules
 from mythril_tpu.analysis.symbolic import SymExecWrapper
 from mythril_tpu.frontend.evmcontract import EVMContract

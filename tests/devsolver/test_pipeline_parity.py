@@ -9,15 +9,10 @@ run must actually route queries through the tier.
 
 import pytest
 
+from bench_contracts import GATED
 from mythril_tpu import devsolver
 from mythril_tpu.observability.metrics import get_registry
 from mythril_tpu.support.support_args import args as global_args
-
-# x = calldataload(0); require(x < 10); x == 5 -> selfdestruct (feasible),
-# x == 20 -> selfdestruct (infeasible) — the bench gated workload
-GATED = bytes.fromhex(
-    "60003580600a9010600c57005b80600514601c5780601414601c57005b33ff"
-)
 
 
 def _analyze(code: bytes, dev: bool):
@@ -83,3 +78,45 @@ def test_pipelined_gated_branch_parity_on_vs_off():
     assert attempted > 0, "devsolver-on run never admitted a query"
     assert off_attempted == 0, "devsolver-off run touched the tier"
     assert bad == 0, "validated-model contract violated during e2e run"
+
+
+# x = cld(0); y = cld(32); require x < 16, y < 16, x == y; the selfdestruct
+# is guarded by ((x ^ y) & 15) == 15: a relational contradiction that
+# intervals and known bits cannot see (neither var is pinned to a point)
+# but bit-level search refutes after one decision
+RELATIONAL = bytes.fromhex(
+    "6000358060109010600c57005b"
+    "6020358060109010601957005b"
+    "80821460215700" "5b"
+    "18600f16600f14602d57005b"
+    "33ff"
+)
+
+
+@pytest.mark.parametrize("name", ["suicide", "gated", "relational",
+                                  "killbilly"])
+def test_devsolver_keeps_issue_set_and_decides_relational(name, monkeypatch):
+    """--no-devsolver parity: identical issue sets, the off run admits
+    nothing, and the relational contradiction is decided UNSAT by the
+    device tier rather than left to the exact tiers."""
+    from tests.parity import exploit, parity_run
+
+    # the relational contradiction must report nothing; killbilly runs
+    # every detection module, the others the kill detector alone
+    target, txs = (RELATIONAL, 1) if name == "relational" else exploit(name)
+    modules = None if name == "killbilly" else ["AccidentallyKillable"]
+    swc = None if name == "relational" else "106"
+    runs = {
+        on: parity_run(monkeypatch, target, txs, modules, ("devsolver.",),
+                       devsolver=on, pipeline=True, frontier_mesh=False)
+        for on in (True, False)
+    }
+    (on, on_snap), (off, off_snap) = runs[True], runs[False]
+    if swc is None:
+        assert not on, f"infeasible branch reported: {on}"
+    else:
+        assert any(s == swc for s, _ in on), f"recall lost: {on}"
+    assert on == off
+    assert off_snap.get("devsolver.admitted", 0) == 0, off_snap
+    if name == "relational":
+        assert on_snap["devsolver.decided_unsat"] > 0, on_snap

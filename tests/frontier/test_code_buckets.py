@@ -2,7 +2,7 @@
 
 One creation-heavy outlier used to inflate the corpus-wide
 ``multi_size_bucket`` so every small code paid the outlier's padded
-instruction axis (the BENCH_r19 bectoken collapse).  Bucket classes give
+instruction axis.  Bucket classes give
 each size cluster its own compiled segment; codes beyond the residency
 budget keep only a hot window device-resident, and a cold jump faults to
 the host (``H_PAGE_FAULT``) for a sync-point repack.  The contract under
@@ -284,7 +284,7 @@ def test_device_dispatch_faults_off_window_pc(paging_defaults):
 
 def _pad_tail_kill(n_pad: int) -> bytes:
     """Selector dispatch to CALLER;SELFDESTRUCT placed BEYOND a straight-
-    line pad tail — the deep cold-jump shape (bench.py largecode_mixed)."""
+    line pad tail — the deep cold-jump shape of a creation-heavy outlier."""
     sel = 0x41C0E1B5  # kill()
     tail = bytes([0x60, 0x00, 0x50]) * n_pad + bytes([0x00])
     dest = 16 + len(tail)
@@ -349,3 +349,43 @@ def test_paged_vs_resident_issue_parity():
         "deep SELFDESTRUCT must surface exactly once"
     )
     assert faults > 0, "the cold-jump target never faulted the window"
+
+
+def _mixed_batch(name):
+    from bench_contracts import GATED, SUICIDE
+
+    # small codes share a batch with outliers big enough to page at the
+    # default residency budget: (jobs, transactions)
+    return {
+        "largecode_mixed": ([("bigkill", _pad_tail_kill(1500)),
+                             ("suicide", SUICIDE), ("gated", GATED)], 2),
+        "two_outliers": ([("big_a", _pad_tail_kill(1200)),
+                          ("big_b", _pad_tail_kill(2400)),
+                          ("suicide", SUICIDE)], 1),
+    }[name]
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("name", ["largecode_mixed", "two_outliers"])
+def test_mixed_size_batch_splits_classes_and_pages(name, monkeypatch):
+    """--no-code-paging parity on mixed-size cooperative batches: identical
+    issue sets with SWC-106 found; the off run clusters nothing; the on run
+    splits into more than one bucket class, wastes less padding than one
+    corpus-wide bucket would, and page-faults on the deep cold jumps."""
+    from tests.parity import cooperative_parity_run
+
+    jobs, txs = _mixed_batch(name)
+    runs = {
+        paged: cooperative_parity_run(
+            monkeypatch, jobs, txs, ("frontier.",), timeout=180,
+            code_paging=paged, pipeline=True, frontier_mesh=False)
+        for paged in (False, True)
+    }
+    (on, snap, _), (off, off_snap, _) = runs[True], runs[False]
+    assert "106" in {swc for _, swc, _, _ in on}, on
+    assert on == off
+    assert not off_snap["frontier.bucket_classes"], off_snap
+    assert snap["frontier.bucket_classes"] > 1, snap
+    assert (snap["frontier.pad_waste_pct"]
+            < snap["frontier.pad_waste_single_bucket_pct"]), snap
+    assert snap["frontier.page_faults"] > 0, snap

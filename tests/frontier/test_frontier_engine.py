@@ -115,23 +115,19 @@ def test_differential_timestamp():
 
 def test_multi_tx_killbilly_exploit():
     """2-tx storage-gated selfdestruct: tx reseeding + storage encode must
-    chain through the device frontier (bench.py's headline workload)."""
-    import pathlib
-    import sys
-
-    sys.path.insert(0, str(pathlib.Path(__file__).parents[2]))
-    import bench
+    chain through the device frontier."""
+    from bench_contracts import check_recall, run_analysis
 
     old = global_args.frontier
     old_force = global_args.frontier_force
     global_args.frontier = True
     global_args.frontier_force = True
     try:
-        _sym, issues, _wall = bench.run_analysis("auto")
+        _sym, issues, _wall = run_analysis("auto")
     finally:
         global_args.frontier = old
         global_args.frontier_force = old_force
-    bench.check_recall(issues)
+    check_recall(issues)
 
 
 def test_arena_const_value_full_width():

@@ -352,21 +352,14 @@ def _run_marked(code, txs, modules, workers):
     return _issue_keys(issues), _frontier_marks()
 
 
-def _fork_heavy() -> bytes:
-    """8 reconvergent symbolic branches (256 concurrent paths) ending in an
-    unguarded SELFDESTRUCT: every path is a terminal replay, the shape that
-    maximizes replay-pool pressure."""
-    out = b""
-    for k in range(8):
-        dest = len(out) + 10
-        out += bytes([0x60, k, 0x35, 0x60, 0x01, 0x16,
-                      0x61, (dest >> 8) & 0xFF, dest & 0xFF, 0x57, 0x5B])
-    return out + bytes([0x33, 0xFF])
-
-
 @pytest.mark.slow
 def test_harvest_parity_fork_heavy():
-    code = _fork_heavy()
+    # 8 reconvergent symbolic branches (256 concurrent paths) ending in an
+    # unguarded SELFDESTRUCT: every path is a terminal replay, the shape
+    # that maximizes replay-pool pressure
+    from bench_contracts import wide_contract
+
+    code = wide_contract(8)
     serial_issues, serial_marks = _run_marked(
         code, 1, ["AccidentallyKillable"], 0
     )
@@ -400,6 +393,32 @@ def test_harvest_parity_multi_tx_storage_gate():
     )
     assert sharded_issues == serial_issues
     assert sharded_marks == serial_marks
+
+
+@pytest.mark.parametrize("name", [
+    "killbilly", pytest.param("wide_fork", marks=pytest.mark.slow),
+])
+def test_sharded_harvest_uses_pool_and_keeps_issue_set(name, monkeypatch):
+    """--harvest-workers 4 vs 0: identical issue sets with SWC-106 found,
+    the sharded run dispatched replays to the pool, the serial run none."""
+    from bench_contracts import wide_contract
+    from tests.parity import exploit, parity_run
+
+    if name == "wide_fork":  # 256 fork-chained paths, harvest-bound
+        target, txs = wide_contract(8), 1
+    else:
+        target, txs = exploit(name)
+    runs = {
+        workers: parity_run(monkeypatch, target, txs,
+                            ["AccidentallyKillable"], ("frontier.",),
+                            harvest_workers=workers, frontier_mesh=False)
+        for workers in (4, 0)
+    }
+    (sharded, sharded_snap), (serial, serial_snap) = runs[4], runs[0]
+    assert any(swc == "106" for swc, _ in sharded), sharded
+    assert sharded == serial
+    assert sharded_snap["frontier.harvest.sharded_paths"] > 0, sharded_snap
+    assert serial_snap["frontier.harvest.sharded_paths"] == 0, serial_snap
 
 
 # ---------------------------------------------------------------------------

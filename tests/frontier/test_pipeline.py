@@ -212,3 +212,23 @@ def test_pipeline_parity_multi_tx_storage_gate():
     piped = _analyze(code, 2, ["AccidentallyKillable"], pipeline=True)
     sync = _analyze(code, 2, ["AccidentallyKillable"], pipeline=False)
     assert _issue_keys(piped) == _issue_keys(sync)
+
+
+@pytest.mark.parametrize("name", ["suicide", "killbilly"])
+def test_pipelined_matches_synchronous_and_chains(name, monkeypatch):
+    """The --no-pipeline contract on the exploit shapes: identical issue
+    sets, the pipelined run chained segments, the synchronous run none."""
+    from tests.parity import exploit, parity_run
+
+    target, txs = exploit(name)
+    runs = {
+        piped: parity_run(monkeypatch, target, txs, ["AccidentallyKillable"],
+                          ("pipeline.",), pipeline=piped,
+                          frontier_mesh=False)
+        for piped in (True, False)
+    }
+    (on, on_snap), (off, off_snap) = runs[True], runs[False]
+    assert any(swc == "106" for swc, _ in on), f"recall lost: {on}"
+    assert on == off
+    assert on_snap["pipeline.segments_pipelined"] > 0, on_snap
+    assert off_snap["pipeline.segments_pipelined"] == 0, off_snap

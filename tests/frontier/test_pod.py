@@ -251,3 +251,37 @@ def test_pod_parity_multi_tx_storage_gate():
         f"sharded run never chained a dispatch: {snap}"
     )
     assert snap.get("pipeline.mesh_shards", 0) == n_dev
+
+
+@pytest.mark.parametrize("name", ["suicide", "killbilly"])
+def test_mesh_and_pipeline_hatches_keep_issue_set(name, monkeypatch):
+    """All four --no-mesh x --no-pipeline combinations find the same
+    issues; pipelined runs chain segments and unpipelined runs do not; mesh
+    runs shard over every device and the sharded-pipelined run attributes
+    delta-pull bytes to every shard."""
+    import jax
+
+    from tests.parity import exploit, parity_run
+
+    n_dev = jax.device_count()
+    assert n_dev == 8, "conftest should pin 8 virtual CPU devices"
+    target, txs = exploit(name)
+    runs = {
+        (mesh, piped): parity_run(
+            monkeypatch, target, txs, ["AccidentallyKillable"],
+            ("pipeline.", "frontier."), frontier_mesh=mesh, pipeline=piped)
+        for mesh in (True, False) for piped in (True, False)
+    }
+    ref, _ = runs[(False, True)]
+    assert any(swc == "106" for swc, _ in ref), ref
+    for (mesh, piped), (issues, snap) in runs.items():
+        assert issues == ref, (mesh, piped)
+        chained = snap["pipeline.segments_pipelined"]
+        assert (chained > 0) if piped else (chained == 0), (mesh, piped, snap)
+        assert snap["frontier.mesh_devices"] == (n_dev if mesh else 0), (
+            mesh, piped)
+    pod = runs[(True, True)][1]
+    assert pod["pipeline.delta_pulls"] > 0, pod
+    by_shard = pod["pipeline.delta_pull_bytes_by_shard"]
+    assert len(by_shard) == n_dev and all(v > 0 for v in by_shard.values()), (
+        by_shard)

@@ -1,9 +1,6 @@
 """Drift doctor: ranked attribution over bench pairs and history windows."""
 
 import json
-from pathlib import Path
-
-import pytest
 
 from mythril_tpu.observability.drift import (
     attribute,
@@ -12,8 +9,6 @@ from mythril_tpu.observability.drift import (
     format_drift,
     load_bench_table,
 )
-
-_REPO = Path(__file__).resolve().parents[2]
 
 
 def _row(production=100.0, baseline=50.0, **over):
@@ -190,18 +185,3 @@ def test_history_window_mode_empty():
     assert report["ranked"] == []
     assert report["headline"] == "drift: history is empty"
 
-
-@pytest.mark.skipif(
-    not ((_REPO / "BENCH_r13.json").exists()
-         and (_REPO / "BENCH_r15.json").exists()),
-    reason="repo bench artifacts not present",
-)
-def test_repo_artifacts_r13_vs_r15_name_bectoken():
-    """The acceptance drill: the r13 -> r15 pair must attribute movement
-    to bectoken_batch (the workload the r15 table visibly lost)."""
-    prior = load_bench_table(str(_REPO / "BENCH_r13.json"))
-    current = load_bench_table(str(_REPO / "BENCH_r15.json"))
-    assert prior and current
-    report = diff_tables(prior, current, "BENCH_r13", "BENCH_r15")
-    top5 = [f["workload"] for f in report["ranked"][:5]]
-    assert "bectoken_batch" in top5

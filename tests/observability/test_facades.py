@@ -46,19 +46,18 @@ def test_frontier_as_dict_populated_matches_seed_shape():
     stats.harvest_s += 0.98765
     stats.mesh_devices = 8
     stats.mid_injections += 1
+    stats.semantic_parks += 2
     stats.record_park("CALL")
     stats.record_park("CALL")
     stats.record_park("SHA3")
     stats.record_bulk_park("timeout", 5)
     stats.record_bulk_park("noop", 0)  # n=0 must not create a key
-    stats.microbench = {"segment_compute_s": 0.1}
     assert json.dumps(stats.as_dict()) == (
         '{"device_instructions": 1000, "device_paths": 3, "segments": 2, '
         '"mesh_devices": 8, "segment_s": 1.235, "harvest_s": 0.988, '
-        '"mid_injections": 1, "mid_encode_failures": 0, "semantic_parks": 0, '
+        '"mid_injections": 1, "mid_encode_failures": 0, "semantic_parks": 2, '
         '"parks_by_opcode": {"CALL": 2, "SHA3": 1}, '
-        '"parks_by_reason": {"timeout": 5, "opcode": 3}, '
-        '"microbench": {"segment_compute_s": 0.1}}'
+        '"parks_by_reason": {"timeout": 5, "opcode": 3}}'
     )
 
 

@@ -67,6 +67,7 @@ def test_pool_digests_bit_identical_to_solo(scoped_args):
         assert solo.stop(drain=True, timeout=30) is True
     assert want["kill"] and not want["clean"]
 
+    r0 = _restarts_now()
     pool = AnalysisService(_config(workers=2)).start()
     try:
         assert pool.wait_warm(timeout=600) is True
@@ -80,6 +81,7 @@ def test_pool_digests_bit_identical_to_solo(scoped_args):
     finally:
         assert pool.stop(drain=True, timeout=60) is True
     assert got == want
+    assert _restarts_now() == r0, "a pool worker restarted"
 
 
 def test_worker_crash_errors_only_its_requests(scoped_args):

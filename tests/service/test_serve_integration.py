@@ -7,6 +7,7 @@ from pathlib import Path
 
 import pytest
 
+from bench_contracts import KILLBILLY, etherstore_like, rubixi_like, wide_contract
 from mythril_tpu.service import (
     AnalysisOptions,
     AnalysisService,
@@ -25,17 +26,6 @@ KILL_SIMPLE_HEX = (
 CLEAN_HEX = "0x60006000f3"
 
 OPTS = AnalysisOptions(transaction_count=2, execution_timeout=60)
-
-
-def _etherstore_hex() -> str:
-    import sys
-
-    sys.path.insert(0, str(REPO))
-    try:
-        from bench_contracts import etherstore_like
-    finally:
-        sys.path.pop(0)
-    return etherstore_like().hex()
 
 
 def _solo_digests(contracts):
@@ -91,7 +81,7 @@ def test_concurrent_clients_bit_identical_to_solo(scoped_args):
 
     contracts = [
         ("kill", KILL_SIMPLE_HEX),
-        ("etherstore", _etherstore_hex()),
+        ("etherstore", etherstore_like().hex()),
         ("clean", CLEAN_HEX),
     ]
 
@@ -191,3 +181,94 @@ def test_server_ping_and_malformed_request(scoped_args):
             client.submit("not-hex", name="bad2")
     finally:
         server.stop(drain=True, timeout=30)
+
+
+def _serve_load_requests():
+    """The synthetic traffic: 8 clients cycling over four contracts, so
+    every contract is submitted twice (dedup by construction)."""
+    contracts = [
+        ("killbilly", KILLBILLY),
+        ("etherstore", etherstore_like().hex()),
+        ("rubixi", rubixi_like().hex()),
+        ("wide4", wide_contract(4).hex()),
+    ]
+    return contracts, [
+        (f"client{i}", *contracts[i % len(contracts)],
+         "interactive" if i % 4 == 0 else "batch")
+        for i in range(8)
+    ]
+
+
+@pytest.mark.parametrize("workers", [1, 2])
+def test_serve_load_traffic_matches_solo(scoped_args, workers):
+    """Concurrent in-process submitters, the tracer on: every request's
+    issue digests equal the solo one-shot run of its contract, and the
+    service drains cleanly.  One worker dedups the duplicates; a pool of
+    two restarts no worker and every worker reports over the fleet
+    fabric."""
+    from mythril_tpu.observability.metrics import get_registry
+    from mythril_tpu.observability.tracer import get_tracer
+    from mythril_tpu.support.support_args import args
+
+    contracts, requests = _serve_load_requests()
+    args.frontier = False
+    args.probe_backend = "host"
+    solo = _solo_digests(contracts)
+
+    reg = get_registry()
+    dedup0 = reg.counter("service.dedup_hits", persistent=True).snapshot() or 0
+    restarts0 = reg.counter(
+        "service.worker_restarts", persistent=True).snapshot() or 0
+    tracer = get_tracer()
+    tracer.reset()
+    tracer.enabled = True
+    service = AnalysisService(ServiceConfig(
+        default_options=OPTS,
+        # a pool caps batch width so admitted work fans out over workers
+        max_batch_width=len(requests) if workers == 1 else 4,
+        batch_window_s=0.25 if workers == 1 else 0.05,
+        frontier=False,
+        probe=True,
+        warmup=True,
+        workers=workers,
+        trace=workers > 1,
+        flush_interval_s=0.25,
+    )).start()
+    results = {}
+    lock = threading.Lock()
+
+    def _submit(client, cname, code, tier):
+        _req, stream, _deduped = service.submit(code, name=client, tier=tier,
+                                                tenant=client)
+        summary = stream.result(timeout=600)
+        with lock:
+            results[client] = (
+                cname, sorted(issue_digest(i) for i in summary["issues"]))
+
+    try:
+        assert service.wait_warm(timeout=600) is True
+        threads = [threading.Thread(target=_submit, args=req, daemon=True)
+                   for req in requests]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=900)
+        fleet = service.fleet.summary() if workers > 1 else None
+    finally:
+        drained = service.stop(drain=True, timeout=120)
+        tracer.enabled = False
+    foreign_spans = tracer.summary().get("foreign_spans", 0)
+    tracer.reset()
+
+    assert drained is True
+    assert len(results) == len(requests), "a request never completed"
+    for client, (cname, digests) in results.items():
+        assert digests == solo[cname], f"{client} ({cname}) diverged"
+    if workers == 1:
+        assert (reg.counter("service.dedup_hits", persistent=True).snapshot()
+                or 0) > dedup0
+    else:
+        assert (reg.counter("service.worker_restarts", persistent=True)
+                .snapshot() or 0) == restarts0
+        assert len(fleet["workers"]) == workers, fleet
+        assert foreign_spans > 0

@@ -2,7 +2,7 @@
 
 import pytest
 
-import bench
+from bench_contracts import KILLBILLY, KILLBILLY_CREATION
 from mythril_tpu.frontend.disassembler import Disassembly
 from mythril_tpu.frontier import taint
 from mythril_tpu.staticpass import (
@@ -16,7 +16,7 @@ from mythril_tpu.support.support_args import args
 
 
 def _killbilly_view() -> GateView:
-    code = bytes.fromhex(bench.KILLBILLY)
+    code = bytes.fromhex(KILLBILLY)
     s = summarize(Disassembly(code).instruction_list, code_size=len(code))
     return GateView([s], contract_name="killbilly")
 
@@ -93,13 +93,13 @@ def _staticpass_enabled():
 
 def test_gate_view_none_when_disabled(_staticpass_enabled):
     args.staticpass = False
-    assert gate_view_for_contract(bytes.fromhex(bench.KILLBILLY)) is None
+    assert gate_view_for_contract(bytes.fromhex(KILLBILLY)) is None
 
 
 def test_gate_view_none_on_resume(_staticpass_enabled):
     assert (
         gate_view_for_contract(
-            bytes.fromhex(bench.KILLBILLY), resume_from="/tmp/ckpt"
+            bytes.fromhex(KILLBILLY), resume_from="/tmp/ckpt"
         )
         is None
     )
@@ -110,7 +110,7 @@ def test_gate_view_none_with_active_dynloader(_staticpass_enabled):
         active = True
 
     assert (
-        gate_view_for_contract(bytes.fromhex(bench.KILLBILLY), dynloader=_Dyn())
+        gate_view_for_contract(bytes.fromhex(KILLBILLY), dynloader=_Dyn())
         is None
     )
 
@@ -118,11 +118,11 @@ def test_gate_view_none_with_active_dynloader(_staticpass_enabled):
 def test_gate_view_none_for_creation_only_contract(_staticpass_enabled):
     from mythril_tpu.frontend.evmcontract import EVMContract
 
-    contract = EVMContract(creation_code=bench.KILLBILLY_CREATION, name="KB")
+    contract = EVMContract(creation_code=KILLBILLY_CREATION, name="KB")
     assert gate_view_for_contract(contract) is None
 
 
 def test_gate_view_for_raw_runtime_bytes(_staticpass_enabled):
-    view = gate_view_for_contract(bytes.fromhex(bench.KILLBILLY))
+    view = gate_view_for_contract(bytes.fromhex(KILLBILLY))
     assert view is not None
     assert "SELFDESTRUCT" in view.reachable_opcodes

@@ -6,8 +6,7 @@ enabled and disabled and asserts byte-identical findings while the gated
 run actually skipped modules and elided hooks.
 """
 
-import bench
-from mythril_tpu.frontend.evmcontract import EVMContract
+from bench_contracts import analyze, clear_caches, killbilly_contract
 from mythril_tpu.observability import get_registry
 from mythril_tpu.staticpass import clear_cache, reset_views
 from mythril_tpu.support.support_args import args
@@ -17,17 +16,12 @@ def _run(staticpass_on: bool):
     prev = args.staticpass
     args.staticpass = staticpass_on
     try:
-        bench._clear_caches()
+        clear_caches()
         clear_cache()
         reset_views()
         get_registry().reset(prefix="staticpass.")
-        contract = EVMContract(
-            code=bench.KILLBILLY,
-            creation_code=bench.KILLBILLY_CREATION,
-            name="KillBilly",
-        )
-        _, issues = bench._analyze(
-            contract, 0x0901D12E, 3, modules=None, timeout=300
+        _, issues = analyze(
+            killbilly_contract(), 0x0901D12E, 3, modules=None, timeout=300
         )
         snap = {
             k: v
